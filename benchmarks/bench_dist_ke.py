@@ -26,21 +26,18 @@ and prints the usual ``name,us_per_call,derived`` CSV rows.
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
+import time
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "")
-    + " --xla_force_host_platform_device_count=8").strip()
+import jax
+import numpy as np
 
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
+from repro.dist.partitioning import make_mesh
+from repro.launch.runtime import force_host_devices
 
 jax.config.update("jax_enable_x64", True)
-
-import numpy as np  # noqa: E402
 
 #: absolute wall-clock ceiling for the 8-device quick gate (seconds). The
 #: pre-rework solver (unconverged at 300 restarts, 3 dispatches/restart)
@@ -54,7 +51,7 @@ def bench_mesh(mesh_shape, n: int, s: int, m: int, p: int,
     from repro.data.problems import md_like
     from repro.dist import eigensolver as de
 
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     prob = md_like(n)
     label = "x".join(str(d) for d in mesh_shape)
 
@@ -93,7 +90,6 @@ def bench_mesh(mesh_shape, n: int, s: int, m: int, p: int,
         "n_restart": info["n_restart"],
         "n_dispatch": max(dispatches),
         "converged": info["converged"],
-        "fused": info["fused"],
         "max_abs_eval_error": err,
     }
 
@@ -136,6 +132,7 @@ def main() -> None:
                     help="assert the CI acceptance gate after measuring")
     ap.add_argument("--outdir", default="artifacts")
     args = ap.parse_args()
+    force_host_devices(8)        # the 8-device host mesh
 
     recs = [bench_mesh((1, 1), args.n, args.s, args.m, args.p,
                        args.filter_degree, args.tol, args.repeats),

@@ -34,22 +34,19 @@ Emits ``artifacts/BENCH_tridiag.json`` and the usual
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
+import time
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "")
-    + " --xla_force_host_platform_device_count=8").strip()
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
+from repro.dist.partitioning import make_mesh
+from repro.launch.runtime import force_host_devices
 
 jax.config.update("jax_enable_x64", True)
-
-import jax.numpy as jnp  # noqa: E402
-import numpy as np       # noqa: E402
 
 #: full-run cells; ``--quick`` keeps only the gated largest cell plus one
 #: small one (compile time, not solve time, dominates the small cells)
@@ -151,11 +148,12 @@ def main() -> None:
                     help="gated cells only + assert the CI acceptance gate")
     ap.add_argument("--outdir", default="artifacts")
     args = ap.parse_args()
+    force_host_devices(8)        # the 8-device host mesh
 
     cell_list = [(512, 8), GATE_CELL] if args.quick else CELLS
     cells = [bench_cell(n, s, args.repeats) for n, s in cell_list]
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     sharded_list = [(512, 64)] if args.quick else [(512, 64), (2048, 64)]
     sharded = [bench_sharded(mesh, n, s, args.repeats)
                for n, s in sharded_list]

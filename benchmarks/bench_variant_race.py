@@ -19,21 +19,18 @@ Emits ``artifacts/BENCH_variant_race.json`` and prints the usual
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
+import time
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "")
-    + " --xla_force_host_platform_device_count=8").strip()
+import jax
+import numpy as np
 
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
+from repro.dist.partitioning import make_mesh
+from repro.launch.runtime import force_host_devices
 
 jax.config.update("jax_enable_x64", True)
-
-import numpy as np  # noqa: E402
 
 
 def bench_variant(variant: str, prob, s: int, band_width: int, m: int,
@@ -99,8 +96,9 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--outdir", default="artifacts")
     args = ap.parse_args()
+    force_host_devices(8)        # the 8-device host mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     out = {"n": args.n, "s": args.s, "mesh": "4x2",
            "n_devices": jax.device_count(), "races": []}
     p_blk = args.p

@@ -124,7 +124,8 @@ def make_mesh_2dev(shape: Tuple[int, int] = (2, 1)):
     """The audit mesh: data=2 so the row collectives are real, not no-ops.
     Requires >= 2 visible devices (``launch/audit.py`` forces host devices
     before importing jax, the ``launch/eigsolve.py`` idiom)."""
-    return jax.make_mesh(shape, ("data", "model"))
+    from repro.dist.partitioning import make_mesh
+    return make_mesh(shape, ("data", "model"))
 
 
 # ------------------------------------------------------------------------

@@ -42,7 +42,7 @@ from collections import Counter
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # jaxpr primitive name -> canonical collective kind (the HLO-level name)
 COLLECTIVE_KINDS: Dict[str, str] = {

@@ -9,10 +9,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .looped import solve_upper
+
 
 def back_transform_generalized(U: jax.Array, Y: jax.Array) -> jax.Array:
     """BT1: X = U^{-1} Y, the final map from STDEIG to GSYEIG eigenvectors."""
-    return jax.scipy.linalg.solve_triangular(U, Y, trans=0, lower=False)
+    return solve_upper(U, Y)
 
 
 def forward_transform_generalized(U: jax.Array, X: jax.Array) -> jax.Array:

@@ -31,7 +31,7 @@ from .cholesky import cholesky_upper
 from .lanczos import default_subspace, lanczos_solve_jit
 from .operators import ExplicitC, ImplicitC
 from .precision import (compute_dtype, default_refine_steps, ensure_strong,
-                        validate_precision)
+                        exact_matmuls, validate_precision)
 from .refinement import default_guard, refine_eigenpairs_fixed
 from .residuals import b_normalize
 from .sbr import apply_q2, band_chase, reduce_to_band
@@ -248,6 +248,7 @@ def clear_pipeline_cache() -> None:
 # public driver
 # --------------------------------------------------------------------------
 
+@exact_matmuls
 def solve_batched(
     A: jax.Array,
     B: jax.Array,

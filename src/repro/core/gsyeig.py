@@ -34,7 +34,8 @@ from .back_transform import back_transform_generalized
 from .cholesky import cholesky_blocked, cholesky_upper, diag_shifted
 from .lanczos import default_subspace, lanczos_solve
 from .operators import ExplicitC, ImplicitC
-from .precision import compute_dtype, ensure_strong, validate_precision
+from .precision import (compute_dtype, ensure_strong, exact_matmuls,
+                        validate_precision)
 from .refinement import REFINE_TOL, refine_eigenpairs
 from . import sbr as _sbr
 from .sbr import apply_q2, band_chase, default_n_chunks, reduce_to_band
@@ -503,6 +504,7 @@ def _finalize(lam, X, A_orig, B_orig, which_orig: str, invert: bool,
     return GSyEigResult(evals=lam, X=X, stage_times=times, info=info)
 
 
+@exact_matmuls
 def solve(
     A: jax.Array,
     B: jax.Array,

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .instrument import DispatchCounter
+from .looped import eigh_small, orthonormalize, qr_posdiag
 from .operators import ExplicitC, ImplicitC, Operator, apply_op, op_dim
 
 
@@ -59,15 +60,6 @@ class LanczosResult(NamedTuple):
 # ---------------------------------------------------------------------------
 # one block step + the jitted whole-segment program
 # ---------------------------------------------------------------------------
-
-def _qr_posdiag(W: jax.Array):
-    """Reduced QR with the R diagonal forced nonnegative (deterministic;
-    for p == 1 this is exactly the classical v = w/||w||, beta = ||w||)."""
-    Q, R = jnp.linalg.qr(W)
-    sgn = jnp.sign(jnp.diagonal(R))
-    sgn = jnp.where(sgn == 0, jnp.ones_like(sgn), sgn)
-    return Q * sgn[None, :], R * sgn[:, None]
-
 
 def _block_step_impl(matvec, V: jax.Array, T: jax.Array, j: jax.Array,
                      p: int):
@@ -90,7 +82,7 @@ def _block_step_impl(matvec, V: jax.Array, T: jax.Array, j: jax.Array,
     H2 = (V.T @ W) * mask
     W = W - V @ H2
     H = H1 + H2                              # (m+p, p) projection coeffs
-    Q, B = _qr_posdiag(W)                    # residual block QR
+    Q, B = qr_posdiag(W)                     # residual block QR
     # block column of T: H on rows < (j+1)p, the new coupling B below
     Hb = H + jax.lax.dynamic_update_slice(
         jnp.zeros_like(H), B, (c0 + p, jnp.zeros((), c0.dtype)))
@@ -180,7 +172,7 @@ def _restart_math(V: jax.Array, T: jax.Array, B_q: jax.Array,
     eps * |theta_i|), so the criterion also accepts bounds under
     ``resid_floor_rel * max|theta|`` — fp64 refinement recovers the rest."""
     Tm = 0.5 * (T[:m, :m] + T[:m, :m].T)
-    theta, S = jnp.linalg.eigh(Tm)  # ascending
+    theta, S = eigh_small(Tm)  # ascending
     if which == "LA":  # want the largest: reorder descending so wanted = first
         theta = theta[::-1]
         S = S[:, ::-1]
@@ -355,7 +347,7 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
         n_matvec += kb + filter_degree * p
     V = jnp.zeros((n, m + p), dtype)
     T = jnp.zeros((m + p, m + p), dtype)
-    Q0, _ = _qr_posdiag(X0)
+    Q0, _ = qr_posdiag(X0)
     V = V.at[:, :p].set(Q0)
 
     j0 = 0
@@ -381,7 +373,7 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
                                  False, resid[:s], healthy=False)
         if conv_ok:
             evecs = V[:, :m] @ S[:, :s]
-            evecs, _ = jnp.linalg.qr(evecs)
+            evecs = orthonormalize(evecs)
             return LanczosResult(theta[:s], evecs, n_matvec, k_restart + 1,
                                  True, resid[:s])
         # thick restart
@@ -389,7 +381,7 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
         j0 = keep // p
 
     evecs = V[:, :m] @ S[:, :s]
-    evecs, _ = jnp.linalg.qr(evecs)
+    evecs = orthonormalize(evecs)
     return LanczosResult(theta[:s], evecs, n_matvec, max_restarts, False,
                          resid[:s])
 
@@ -442,7 +434,7 @@ def lanczos_solve_jit(op: Operator, v0: jax.Array, s: int, m: int,
         theta_p, beta_k = estimate_bounds(matvec, X0[:, 0], kb)
         a, b, a0 = filter_interval(theta_p, beta_k, s, which)
         X0 = chebyshev_filter(matvec, X0, filter_degree, a, b, a0)
-    Q0, _ = _qr_posdiag(X0)
+    Q0, _ = qr_posdiag(X0)
     V0 = jnp.zeros((n, m + p), dtype).at[:, :p].set(Q0)
     T0 = jnp.zeros((m + p, m + p), dtype)
 
@@ -467,5 +459,5 @@ def lanczos_solve_jit(op: Operator, v0: jax.Array, s: int, m: int,
     k, V, T, j0_val, converged, healthy, evals, evecs = jax.lax.while_loop(
         cond, body, state0
     )
-    q, _ = jnp.linalg.qr(evecs)
+    q = orthonormalize(evecs)
     return evals, q, k, converged, healthy

@@ -17,7 +17,7 @@ from typing import NamedTuple, Union
 import jax
 import jax.numpy as jnp
 
-_solve_tri = jax.scipy.linalg.solve_triangular
+from .looped import matmul, solve_upper
 
 
 class ExplicitC(NamedTuple):
@@ -45,7 +45,7 @@ def _symm(M: jax.Array, w: jax.Array, use_kernel: bool) -> jax.Array:
         # XLA fallback of the kernel's fp32-accumulating bf16 MXU path
         return jnp.matmul(M, w, preferred_element_type=jnp.float32) \
             .astype(M.dtype)
-    return M @ w
+    return matmul(M, w)
 
 
 def apply_op(op: Operator, w: jax.Array, use_kernel: bool = False) -> jax.Array:
@@ -58,11 +58,11 @@ def apply_op(op: Operator, w: jax.Array, use_kernel: bool = False) -> jax.Array:
         return _symm(op.C, w, use_kernel)
     if isinstance(op, ImplicitC):
         # KI1: wbar = U^{-1} w
-        wbar = _solve_tri(op.U, w, trans=0, lower=False)
+        wbar = solve_upper(op.U, w)
         # KI2: what = A wbar
         what = _symm(op.A, wbar, use_kernel)
         # KI3: z = U^{-T} what
-        return _solve_tri(op.U, what, trans=1, lower=False)
+        return solve_upper(op.U, what, trans=True)
     raise TypeError(f"unknown operator {type(op)}")
 
 

@@ -21,6 +21,7 @@ policy instead of exempting the mixed pipeline from its dtype lint.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -39,6 +40,23 @@ _DECLARED = {
     "mixed": ("float64->float32",),
     "fast": ("float64->bfloat16", "float64->float32"),
 }
+
+
+def exact_matmuls(fn):
+    """Run ``fn`` with every matmul at its operands' own precision
+    (``jax.default_matmul_precision("highest")``).
+
+    On a TPU the default lets f32 products take bf16 passes; on a v5e an
+    orthogonal factor built and checked with the tiled f64 products of
+    ``core.looped`` at the default read max|Q^T Q - I| ~ 2.5e-6, where a
+    plain f64 dot was exact. The public entry points carry this
+    decorator, so the ``precision=`` axis is set by dtypes alone. On a CPU
+    host f32 and f64 dots are exact either way."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 def validate_precision(precision: str) -> str:

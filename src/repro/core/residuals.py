@@ -10,6 +10,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .looped import matmul
+from .precision import exact_matmuls
+
 
 class AccuracyReport(NamedTuple):
     b_orthogonality: jax.Array
@@ -18,17 +21,18 @@ class AccuracyReport(NamedTuple):
 
 def b_orthogonality(X: jax.Array, B: jax.Array) -> jax.Array:
     s = X.shape[1]
-    G = X.T @ (B @ X)
+    G = X.T @ matmul(B, X)
     return jnp.linalg.norm(G - jnp.eye(s, dtype=X.dtype)) / jnp.linalg.norm(B)
 
 
 def relative_residual(A: jax.Array, B: jax.Array, X: jax.Array,
                       lam: jax.Array) -> jax.Array:
-    R = A @ X - (B @ X) * lam[None, :]
+    R = matmul(A, X) - matmul(B, X) * lam[None, :]
     denom = jnp.maximum(jnp.linalg.norm(A), jnp.linalg.norm(B))
     return jnp.linalg.norm(R) / denom
 
 
+@exact_matmuls
 def accuracy_report(A: jax.Array, B: jax.Array, X: jax.Array,
                     lam: jax.Array) -> AccuracyReport:
     return AccuracyReport(
@@ -39,6 +43,6 @@ def accuracy_report(A: jax.Array, B: jax.Array, X: jax.Array,
 
 def b_normalize(X: jax.Array, B: jax.Array) -> jax.Array:
     """Scale columns of X to unit B-norm (x^T B x = 1)."""
-    nrm = jnp.sqrt(jnp.maximum(jnp.einsum("is,is->s", X, B @ X),
+    nrm = jnp.sqrt(jnp.maximum(jnp.einsum("is,is->s", X, matmul(B, X)),
                                jnp.finfo(X.dtype).tiny))
     return X / nrm[None, :]

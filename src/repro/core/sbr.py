@@ -45,6 +45,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
 from repro.kernels.house_panel.ops import house_panel
 from repro.kernels.rot_apply.ops import rot_apply
 
@@ -118,9 +119,10 @@ def default_n_chunks(n: int, w: int) -> int:
 
 def _wy_rank2_update(Mt: jax.Array, V: jax.Array, T: jax.Array) -> jax.Array:
     """SYR2K-form two-sided update; the rank-2w product goes through the
-    fused ``kernels/syr2k`` Pallas kernel on TPU (one HBM round trip per
-    C tile) and the identical jnp expression elsewhere."""
-    if jax.default_backend() == "tpu":
+    fused ``kernels/syr2k`` Pallas kernel where ``kernels.dispatch`` picks
+    it (a TPU, f32/bf16 operands: one HBM round trip per C tile) and the
+    identical jnp expression elsewhere."""
+    if dispatch.use_pallas(Mt.dtype):
         from repro.kernels.syr2k.ops import syr2k
         Z = wy_syr2k_panel(Mt, V, T)
         return symmetrize(syr2k(Mt, V, Z, alpha=-1.0))

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .linalg_utils import symmetrize
+from .looped import solve_upper
 
 _solve_tri = jax.scipy.linalg.solve_triangular
 
@@ -21,9 +22,9 @@ _solve_tri = jax.scipy.linalg.solve_triangular
 def to_standard_two_trsm(A: jax.Array, U: jax.Array) -> jax.Array:
     """C = U^{-T} A U^{-1} via two TRSMs (2 n^3 flops)."""
     # W = U^{-T} A  : solve U^T W = A
-    W = _solve_tri(U, A, trans=1, lower=False)
+    W = solve_upper(U, A, trans=True)
     # C = W U^{-1}  : C U = W  <=>  U^T C^T = W^T
-    C = _solve_tri(U, W.T, trans=1, lower=False).T
+    C = solve_upper(U, W.T, trans=True).T
     return symmetrize(C)
 
 

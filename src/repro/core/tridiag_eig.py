@@ -213,11 +213,18 @@ class TridiagEigResult(NamedTuple):
     Z: jax.Array    # (n, s) eigenvectors of T
 
 
-def default_tridiag_method() -> str:
-    """Backend-resolved default for ``eigh_tridiag_selected``: the Pallas
-    kernels compiled on a real TPU, the fused-XLA batched program (which
-    beats interpret-mode Pallas by orders of magnitude) everywhere else."""
-    return "kernel" if jax.default_backend() == "tpu" else "batched"
+def default_tridiag_method(dtype=jnp.float32, n: int = 0,
+                           s: int = 0) -> str:
+    """Per-call default for ``eigh_tridiag_selected``: the Pallas kernels
+    where ``kernels.dispatch`` compiles them (a TPU, an f32 tridiagonal
+    whose resident columns fit VMEM), the fused-XLA batched program (which
+    beats interpret-mode Pallas by orders of magnitude) everywhere else —
+    including every f64 TT3/TD2 on a TPU."""
+    from repro.kernels import dispatch
+    from repro.kernels.tridiag_eig.ops import (bisect_vmem_bytes,
+                                               invit_vmem_bytes)
+    vmem = max(bisect_vmem_bytes(n), invit_vmem_bytes(n, s))
+    return "kernel" if dispatch.use_pallas(dtype, vmem) else "batched"
 
 
 def eigh_tridiag_selected(d: jax.Array, e: jax.Array, ks: jax.Array,
@@ -233,8 +240,9 @@ def eigh_tridiag_selected(d: jax.Array, e: jax.Array, ks: jax.Array,
     tests/test_tridiag_eig.py).
 
     method:
-      None      — backend autodetect (:func:`default_tridiag_method`):
-                  'kernel' on a real TPU, 'batched' elsewhere.
+      None      — per-call choice (:func:`default_tridiag_method`):
+                  'kernel' where the Pallas kernels compile for the
+                  platform, dtype and size, 'batched' elsewhere.
       'scan'    — the legacy two-program baseline (bisection jit + inverse
                   iteration jit, unroll=1 Sturm scans).
       'batched' — ONE fused program from ``kernels.tridiag_eig.ops`` with
@@ -245,7 +253,7 @@ def eigh_tridiag_selected(d: jax.Array, e: jax.Array, ks: jax.Array,
                   tests and TPU execution.
     """
     if method is None:
-        method = default_tridiag_method()
+        method = default_tridiag_method(d.dtype, d.shape[0], jnp.shape(ks)[0])
     if key is None:
         key = jax.random.PRNGKey(12021)
     ks = jnp.asarray(ks)

@@ -9,6 +9,11 @@ exactly the chosen spectrum and the eigenvectors are U^{-1} Q.
   * ``dft_like`` — FLEUR/DFT: A symmetric indefinite-ish spectrum with a
     *clustered* lower end, B ≈ overlap matrix close to I; drives Lanczos to
     many iterations (paper Exp. 2's 4k iterations).
+
+At the paper's sizes on a TPU the orthogonal factor is a product of
+random reflectors and the products are tiled (``core.looped``), so
+building a pencil compiles in the same time at any n; elsewhere XLA's QR
+and matmuls run.
 """
 from __future__ import annotations
 
@@ -16,6 +21,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.looped import looped, matmul
+from repro.core.precision import exact_matmuls
 
 
 class GSyEigProblem(NamedTuple):
@@ -25,25 +33,60 @@ class GSyEigProblem(NamedTuple):
     name: str
 
 
+#: reflectors per compact-WY block of the TPU-path orthogonal factor
+_REFLECTORS = 256
+
+
+@jax.jit
+def _reflector_block(Q: jax.Array, key: jax.Array) -> jax.Array:
+    """Q H_1 ... H_b for b reflectors H_j = I - 2 v_j v_j^T about random
+    unit directions, applied as one compact-WY update Q - (Q V) T V^T
+    (T from the forward recurrence of LAPACK's dlarft)."""
+    n = Q.shape[0]
+    b = min(_REFLECTORS, n)
+    V = jax.random.normal(key, (n, b), Q.dtype)
+    V = V / jnp.linalg.norm(V, axis=0, keepdims=True)
+    G = V.T @ V
+    idx = jnp.arange(b)
+
+    def column(j, T):
+        g = jnp.where(idx < j, G[:, j], 0)
+        return T.at[:, j].set(jnp.where(idx == j, 2.0, -2.0 * (T @ g)))
+
+    T = jax.lax.fori_loop(0, b, column, jnp.zeros((b, b), Q.dtype))
+    return Q - matmul(matmul(matmul(Q, V), T), V.T)
+
+
 def _random_orthogonal(n: int, key: jax.Array, dtype) -> jax.Array:
+    """A random orthogonal Q. Off the TPU path: the Q of a Gaussian M = QR
+    with diag(R) > 0. On it: a product of n random reflectors in blocks —
+    orthogonal to rounding by construction, from GEMMs alone. (CholeskyQR
+    of the Gaussian M, plain or shifted, left max|Q^T Q - I| ~ 2e-6 at
+    n=9,997 on a v5e, which shifts the "exact" spectrum by as much.)"""
+    if looped(n):
+        Q = jnp.eye(n, dtype=dtype)
+        for i in range(-(-n // _REFLECTORS)):
+            Q = _reflector_block(Q, jax.random.fold_in(key, i))
+        return Q
     M = jax.random.normal(key, (n, n), dtype)
     Q, R = jnp.linalg.qr(M)
     # fix signs for determinism
     return Q * jnp.sign(jnp.diagonal(R))[None, :]
 
 
+@exact_matmuls
 def _assemble(n: int, spectrum: jax.Array, key: jax.Array, dtype,
               b_offdiag: float, name: str) -> GSyEigProblem:
     kq, ku = jax.random.split(key)
     Q = _random_orthogonal(n, kq, dtype)
-    C = (Q * spectrum[None, :]) @ Q.T
+    C = matmul(Q * spectrum[None, :], Q.T)
     C = 0.5 * (C + C.T)
     # U = I + small strictly-upper noise: B = U^T U is SPD, well conditioned
     noise = jax.random.normal(ku, (n, n), dtype) * (b_offdiag / jnp.sqrt(n))
     U = jnp.eye(n, dtype=dtype) + jnp.triu(noise, k=1)
-    A = U.T @ C @ U
+    A = matmul(matmul(U.T, C), U)
     A = 0.5 * (A + A.T)
-    B = U.T @ U
+    B = matmul(U.T, U)
     B = 0.5 * (B + B.T)
     return GSyEigProblem(A=A, B=B, exact_evals=jnp.sort(spectrum), name=name)
 

@@ -33,30 +33,32 @@ TT (``solve_tt_distributed``, the ELPA2-style two-stage path):
        slab + collective-free panel matmul against the mesh-resident Q1)
   BT1  X = U^{-1} Y                          (dist_trsm_left)
 
-The Lanczos driver itself is ``core.lanczos.lanczos_solve`` — the
-distributed path supplies a matvec closure instead of duplicating the
-restart logic. ``core.gsyeig.solve(..., mesh=...)`` dispatches here.
+The Krylov stage shares ``core.lanczos``'s block segment and restart math
+(``_segment_impl`` / ``_restart_math``) — the distributed path supplies a
+matvec closure instead of duplicating the restart logic.
+``core.gsyeig.solve(..., mesh=...)`` dispatches here.
 """
 from __future__ import annotations
 
 import functools
+import math
 import time
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.filtering import (chebyshev_filter, estimate_bounds,
                                   filter_interval, probe_steps)
 from repro.core.instrument import DispatchCounter
-from repro.core.lanczos import (_qr_posdiag, _restart_math, _segment_impl,
-                                default_subspace, lanczos_solve,
-                                restart_schedule)
+from repro.core.lanczos import (_restart_math, _segment_impl,
+                                default_subspace, restart_schedule)
 from repro.core.linalg_utils import symmetrize
-from repro.core.operators import ExplicitC
-from repro.core.precision import compute_dtype, validate_precision
+from repro.core.looped import orthonormalize, pad_identity, qr_posdiag
+from repro.core.precision import (compute_dtype, exact_matmuls,
+                                  validate_precision)
 from repro.core.sbr import (_jit_house_panel, _jit_pack, _jit_slice_cols,
                             _n_panels, apply_q2, band_chase)
 from repro.core.tridiag_eig import (TridiagEigResult, _cluster_ids,
@@ -64,6 +66,7 @@ from repro.core.tridiag_eig import (TridiagEigResult, _cluster_ids,
                                     bisect_eigenvalues,
                                     eigh_tridiag_selected)
 from repro.kernels.tridiag_eig.ops import SCAN_UNROLL
+from .partitioning import auto_axes
 from .sharded_la import (_n_row_shards, _row_axes, _row_spec, _row_sharded,
                          band_sweep_program, dist_apply_wy_right,
                          dist_apply_wy_two_sided, dist_cholesky,
@@ -143,7 +146,7 @@ def ke_restart_program(mesh, n: int, p: int, m: int, s: int, keep: int,
     Returns a jitted ``(C, V, T, j0, tol_eff) ->
     (theta (s,), resid (s,), V', T', converged, healthy, evecs (n, s))``
     callable; V/T are donated. Requires n divisible by both mesh tilings
-    (``solve_ke_distributed`` falls back to a replicated operator else).
+    (``solve_ke_distributed`` zero-pads the operator to such an n).
     """
     rs, ax, R, cm, ok = _mesh_tiling(mesh, n)
     assert ok, (n, R, cm)
@@ -157,7 +160,7 @@ def ke_restart_program(mesh, n: int, p: int, m: int, s: int, keep: int,
         # convergence scalar, zero extra dispatches
         theta, S, resid, V_r, T_new, conv, healthy = _restart_math(
             V, T, B_q, tol_eff, s=s, keep=keep, m=m, p=p, which=which)
-        evecs, _ = jnp.linalg.qr(V[:, :m] @ S[:, :s])
+        evecs = orthonormalize(V[:, :m] @ S[:, :s])
         return theta[:s], resid[:s], V_r, T_new, conv, healthy, evecs
 
     prog = shard_map(local, mesh=mesh,
@@ -165,7 +168,7 @@ def ke_restart_program(mesh, n: int, p: int, m: int, s: int, keep: int,
                                P(), P()),
                      out_specs=(P(None), P(None), P(None, None),
                                 P(None, None), P(), P(), P(None, None)),
-                     check_rep=False)
+                     check_vma=False)
     return jax.jit(prog, donate_argnums=(1, 2))
 
 
@@ -186,16 +189,17 @@ def ke_prep_program(mesh, n: int, p: int, kb: int, degree: int, s: int,
         theta, beta_k = estimate_bounds(matvec, X0[:, 0], kb)
         a, b, a0 = filter_interval(theta, beta_k, s, which)
         Xf = chebyshev_filter(matvec, X0, degree, a, b, a0)
-        Q0, _ = _qr_posdiag(Xf)
+        Q0, _ = qr_posdiag(Xf)
         return Q0
 
     prog = shard_map(local, mesh=mesh,
                      in_specs=(P(rs, "model"), P(None, None)),
                      out_specs=P(None, None),
-                     check_rep=False)
+                     check_vma=False)
     return jax.jit(prog)
 
 
+@exact_matmuls
 def solve_ke_distributed(
     mesh,
     A: jax.Array,
@@ -253,6 +257,7 @@ def solve_ke_distributed(
     and Lanczos counters (n_matvec, n_restart, converged, healthy).
     """
     validate_precision(precision)
+    mesh = auto_axes(mesh)
     demoted = precision != "fp64"
     cdtype = compute_dtype(precision)
     B_orig = B
@@ -268,116 +273,105 @@ def solve_ke_distributed(
     times = {}
     timed = _make_timer(times)
 
+    # an n that does not tile the mesh is padded to one that does: A with
+    # zeros, B with an identity block. Then U and C are block-diagonal, C
+    # with an exactly zero block, and the starting block is zero on the
+    # padded rows — so the Krylov space never leaves the first n rows
+    # (every matvec, re-orthogonalization and QR keeps them exactly zero)
+    # and the padding changes no eigenpair of the solve
+    rs, ax, R, cm, _ = _mesh_tiling(mesh, n)
+    n_k = -(-n // math.lcm(R, cm)) * math.lcm(R, cm)
+    if n_k != n:
+        A = jnp.pad(A, ((0, n_k - n), (0, n_k - n)))
+        B = pad_identity(B, n_k)
     U, C = _standard_form(mesh, A, B, timed)
     arp_which = "SA" if which == "smallest" else "LA"
     # work dtype of the basis/restart math; the operand may sit lower
     wdtype = jnp.float32 if demoted else C.dtype
     keep, _ = restart_schedule(s, m, p)
-    rs, ax, R, cm, divisible = _mesh_tiling(mesh, n)
 
     t0 = time.perf_counter()
     healthy = True
     resumed_from = None
-    if not divisible:
-        # uneven tilings cannot shard_map; keep GS1/GS2/BT1 distributed and
-        # run the (block) Lanczos stage on the replicated operator — still
-        # the shared core, just without the mesh collectives. Checkpointing
-        # rides the host loop's callback hook (resume is fused-path only).
-        callback = None
-        if checkpoint_dir is not None:
-            from . import checkpoint as _ckpt
-            callback = _ckpt.lanczos_callback(checkpoint_dir,
-                                              every=checkpoint_every,
-                                              keep=checkpoint_keep)
-        C_rep = jax.device_put(C, NamedSharding(mesh, P(None, None)))
-        res = lanczos_solve(ExplicitC(C_rep), s, which=arp_which, m=m,
-                            tol=tol, max_restarts=max_restarts, key=key,
-                            p=p, filter_degree=filter_degree,
-                            callback=callback,
-                            compute_dtype=cdtype if demoted else None)
-        lam, Y = res.evals, res.evecs
-        n_matvec, n_restart = res.n_matvec, res.n_restart
-        converged = res.converged
-        healthy = bool(res.healthy)
+    # the Krylov operand lives 2-D-sharded: rows over data axes, cols over
+    # 'model' — the layout the fused block matvec consumes
+    if demoted:
+        C = C.astype(cdtype)
+    dtype = C.dtype
+    C = jax.device_put(C, NamedSharding(mesh, P(rs, "model")))
+    rep = NamedSharding(mesh, P(None, None))
+    dname = jnp.dtype(dtype).name
+
+    def padded(M):                  # (n, k) -> (n_k, k), replicated
+        return jax.device_put(jnp.pad(M, ((0, n_k - n), (0, 0))), rep)
+
+    X0 = padded(jax.random.normal(key, (n, p), wdtype))
+    n_matvec = 0
+    if filter_degree > 0:
+        kb = probe_steps(s, n)
+        prep = ke_prep_program(mesh, n_k, p, kb, filter_degree, s,
+                               arp_which, dname)
+        Q0 = _dispatch(prep, C, X0)
+        n_matvec += kb + filter_degree * p
     else:
-        # the Krylov operand lives 2-D-sharded: rows over data axes, cols
-        # over 'model' — the layout the fused block matvec consumes
-        if demoted:
-            C = C.astype(cdtype)
-        dtype = C.dtype
-        C = jax.device_put(C, NamedSharding(mesh, P(rs, "model")))
-        rep = NamedSharding(mesh, P(None, None))
-        dname = jnp.dtype(dtype).name
-        X0 = jax.device_put(
-            jax.random.normal(key, (n, p), wdtype), rep)
-        n_matvec = 0
-        if filter_degree > 0:
-            kb = probe_steps(s, n)
-            prep = ke_prep_program(mesh, n, p, kb, filter_degree, s,
-                                   arp_which, dname)
-            Q0 = _dispatch(prep, C, X0)
-            n_matvec += kb + filter_degree * p
-        else:
-            Q0, _ = _qr_posdiag(X0)
-        V = jax.device_put(
-            jnp.zeros((n, m + p), wdtype).at[:, :p].set(Q0), rep)
-        T = jax.device_put(jnp.zeros((m + p, m + p), wdtype), rep)
-        # the demoted operand floors the attainable residual at
-        # ~eps(cdtype) * ||C||; ask for no more (core.lanczos uses the
-        # same 8x floor on its local demoted path)
-        eps = float(jnp.finfo(dtype).eps)
-        eps_eff = 8.0 * eps if demoted else eps
-        tol_eff = jnp.asarray(tol if tol > 0.0 else eps_eff, wdtype)
-        prog = ke_restart_program(mesh, n, p, m, s, keep, arp_which, dname)
-        j0 = 0
-        k0 = 0
-        converged = False
-        if checkpoint_dir is not None and resume:
+        Q0, _ = qr_posdiag(X0)
+    V = jax.device_put(
+        jnp.zeros((n_k, m + p), wdtype).at[:, :p].set(Q0), rep)
+    T = jax.device_put(jnp.zeros((m + p, m + p), wdtype), rep)
+    # the demoted operand floors the attainable residual at
+    # ~eps(cdtype) * ||C||; ask for no more (core.lanczos uses the same 8x
+    # floor on its local demoted path)
+    eps = float(jnp.finfo(dtype).eps)
+    eps_eff = 8.0 * eps if demoted else eps
+    tol_eff = jnp.asarray(tol if tol > 0.0 else eps_eff, wdtype)
+    prog = ke_restart_program(mesh, n_k, p, m, s, keep, arp_which, dname)
+    j0 = 0
+    k0 = 0
+    converged = False
+    if checkpoint_dir is not None and resume:
+        from . import checkpoint as _ckpt
+        # dict keys flatten sorted, so the template's {T, V} order matches
+        # what save() wrote; V is stored unpadded, so a resume may land on
+        # a mesh with another padding
+        got = _ckpt.load_latest(
+            checkpoint_dir, {"T": jnp.zeros((m + p, m + p), wdtype),
+                             "V": jnp.zeros((n, m + p), wdtype)})
+        if got is not None:
+            step, tree, extra = got
+            V = padded(tree["V"])
+            T = jax.device_put(tree["T"], rep)
+            j0 = int(extra.get("j", keep // p))
+            k0 = int(step) + 1
+            n_matvec = int(extra.get("n_matvec", n_matvec))
+            resumed_from = int(step)
+    n_restart = max_restarts
+    for k_restart in range(k0, max_restarts):
+        lam, resid, V, T, conv, healthy_dev, Y = _dispatch(
+            prog, C, V, T, jnp.asarray(j0), tol_eff)
+        n_matvec += m - j0 * p
+        j0 = keep // p
+        # one fetch for both fused verdicts
+        conv_ok, health_ok = (bool(x) for x in
+                              jax.device_get((conv, healthy_dev)))
+        if checkpoint_dir is not None and k_restart % checkpoint_every == 0:
+            # the POST-restart (V, T) — the state the next segment consumes
+            # — so a resumed solve replays the identical restart arithmetic
             from . import checkpoint as _ckpt
-            # dict keys flatten sorted, so the template's {T, V} order
-            # matches what save() wrote
-            got = _ckpt.load_latest(
-                checkpoint_dir, {"T": jnp.zeros((m + p, m + p), wdtype),
-                                 "V": jnp.zeros((n, m + p), wdtype)})
-            if got is not None:
-                step, tree, extra = got
-                V = jax.device_put(tree["V"], rep)
-                T = jax.device_put(tree["T"], rep)
-                j0 = int(extra.get("j", keep // p))
-                k0 = int(step) + 1
-                n_matvec = int(extra.get("n_matvec", n_matvec))
-                resumed_from = int(step)
-        n_restart = max_restarts
-        for k_restart in range(k0, max_restarts):
-            lam, resid, V, T, conv, healthy_dev, Y = _dispatch(
-                prog, C, V, T, jnp.asarray(j0), tol_eff)
-            n_matvec += m - j0 * p
-            j0 = keep // p
-            # one fetch for both fused verdicts
-            conv_ok, health_ok = (bool(x) for x in
-                                  jax.device_get((conv, healthy_dev)))
-            if (checkpoint_dir is not None
-                    and k_restart % checkpoint_every == 0):
-                # the POST-restart (V, T) — the state the next segment
-                # consumes — so a resumed solve replays the identical
-                # restart arithmetic
-                from . import checkpoint as _ckpt
-                _ckpt.save(checkpoint_dir, k_restart, {"V": V, "T": T},
-                           extra={"kind": "ke_dist", "j": int(j0),
-                                  "n_matvec": int(n_matvec)},
-                           keep=checkpoint_keep)
-            if preempt_after is not None \
-                    and k_restart - k0 + 1 >= preempt_after:
-                from repro.resilience.faults import SimulatedPreemption
-                raise SimulatedPreemption(k_restart)
-            if not health_ok:
-                healthy = False
-                n_restart = k_restart + 1
-                break
-            if conv_ok:
-                converged = True
-                n_restart = k_restart + 1
-                break
+            _ckpt.save(checkpoint_dir, k_restart, {"V": V[:n], "T": T},
+                       extra={"kind": "ke_dist", "j": int(j0),
+                              "n_matvec": int(n_matvec)},
+                       keep=checkpoint_keep)
+        if preempt_after is not None and k_restart - k0 + 1 >= preempt_after:
+            from repro.resilience.faults import SimulatedPreemption
+            raise SimulatedPreemption(k_restart)
+        if not health_ok:
+            healthy = False
+            n_restart = k_restart + 1
+            break
+        if conv_ok:
+            converged = True
+            n_restart = k_restart + 1
+            break
     jax.block_until_ready(Y)
     times["KE_iter"] = time.perf_counter() - t0
 
@@ -387,7 +381,7 @@ def solve_ke_distributed(
     lam, Y = lam[order], Y[:, order]
 
     # BT1: X = U^{-1} Y
-    X = timed("BT1", lambda y: dist_trsm_left(mesh, U, y), Y)
+    X = timed("BT1", lambda y: dist_trsm_left(mesh, U, y), Y)[:n]
 
     if invert:
         lam = 1.0 / lam
@@ -402,7 +396,10 @@ def solve_ke_distributed(
                 "n_restart": int(n_restart),
                 "converged": bool(converged), "healthy": bool(healthy),
                 "p": int(p), "filter_degree": int(filter_degree),
-                "precision": precision, "fused": bool(divisible)}
+                "precision": precision,
+                "restart_program": {"n": int(n_k), "m": int(m),
+                                    "keep": int(keep), "which": arp_which,
+                                    "dtype": dname}}
         if resumed_from is not None:
             info["resumed_from"] = int(resumed_from)
         return lam, X, info
@@ -574,7 +571,7 @@ def tt3_program(mesh, n: int, s_pad: int, max_iters: int, iters: int,
     prog = shard_map(local, mesh=mesh,
                      in_specs=(P(None), P(None), P(part), P(None, None)),
                      out_specs=(P(None), P(None, None)),
-                     check_rep=False)
+                     check_vma=False)
     return jax.jit(prog)
 
 
@@ -611,6 +608,7 @@ def dist_tridiag_eig(mesh, d: jax.Array, e: jax.Array, ks: jax.Array,
     return TridiagEigResult(lam=lam[:s][inv], Z=Z[:, :s][:, inv])
 
 
+@exact_matmuls
 def solve_tt_distributed(
     mesh,
     A: jax.Array,
@@ -643,6 +641,7 @@ def solve_tt_distributed(
     ``return_info=True`` a third dict carries per-stage wall-clock times.
     """
     validate_precision(precision)
+    mesh = auto_axes(mesh)
     demoted = precision != "fp64"
     cdtype = compute_dtype(precision)
     n = A.shape[0]

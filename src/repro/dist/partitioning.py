@@ -24,9 +24,31 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types.
+
+    Every program here places operands with ``NamedSharding`` and lets XLA
+    propagate (or runs ``shard_map`` regions); ``jax.make_mesh`` defaults
+    to Explicit axes, under which the replicated glue between those
+    regions raises ``ShardingTypeError``."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with Auto axis types — how the
+    distributed solvers accept a mesh built by plain ``jax.make_mesh``."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _mesh_axes(mesh):

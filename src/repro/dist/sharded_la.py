@@ -10,10 +10,11 @@ The decomposition follows the multi-GPU ELPA2 / Solca-Schulthess playbook:
     collective is half the bytes — the right choice when the consumer is
     itself distributed).
   * ``dist_cholesky`` / ``dist_trsm_left_t`` — blocked panel algorithms
-    (right-looking Cholesky, block forward/backward substitution) written
-    against row-block-sharded operands; XLA's SPMD partitioner turns the
-    panel broadcast into one collective per panel, matching the paper's
-    "factor panel, broadcast, update trailing matrix" structure.
+    (right-looking Cholesky, block forward/backward substitution: the
+    one-``fori_loop`` programs of ``core.looped``, whose compile does not
+    grow with n) on row-block-sharded operands; XLA's SPMD partitioner
+    turns the panel broadcast into collectives per panel, matching the
+    paper's "factor panel, broadcast, update trailing matrix" structure.
 
 All entry points accept plain (even single-device) arrays and place them
 onto the mesh themselves, so the same call sites work eagerly in tests and
@@ -26,10 +27,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-_solve_tri = jax.scipy.linalg.solve_triangular
+from repro.core.cholesky import cholesky_blocked
+from repro.core.looped import solve_upper_looped
 
 
 def _row_spec(mesh):
@@ -256,7 +258,7 @@ def band_sweep_program(mesh, n: int, w: int, dtype_name: str):
     sweep = shard_map(local, mesh=mesh,
                       in_specs=(P(rs, None), P(rs, None)),
                       out_specs=(P(rs, None), P(rs, None)),
-                      check_rep=False)
+                      check_vma=False)
     return jax.jit(sweep)
 
 
@@ -279,44 +281,14 @@ def _panel(mesh, n: int, block) -> int:
     return max(min(n // max(_n_row_shards(mesh), 1), 1024), 16)
 
 
-def _chol_blocked(B, block: int):
-    """Right-looking blocked Cholesky, B = U^T U (upper factor)."""
-    n = B.shape[0]
-    M = B
-    U = jnp.zeros_like(B)
-    for k0 in range(0, n, block):
-        k1 = min(k0 + block, n)
-        Ukk = jnp.linalg.cholesky(M[k0:k1, k0:k1]).T
-        U = U.at[k0:k1, k0:k1].set(Ukk)
-        if k1 < n:
-            row = _solve_tri(Ukk, M[k0:k1, k1:], trans=1, lower=False)
-            U = U.at[k0:k1, k1:].set(row)
-            M = M.at[k1:, k1:].add(-(row.T @ row))
-    return jnp.triu(U)
-
-
 def _trsm_lt_blocked(U, B, block: int):
     """Solve U^T W = B (U upper): block forward substitution."""
-    n = U.shape[0]
-    W = jnp.zeros_like(B)
-    for k0 in range(0, n, block):
-        k1 = min(k0 + block, n)
-        rhs = B[k0:k1] - U[:k0, k0:k1].T @ W[:k0]
-        W = W.at[k0:k1].set(_solve_tri(U[k0:k1, k0:k1], rhs, trans=1,
-                                       lower=False))
-    return W
+    return solve_upper_looped(U, B, trans=True, block=block)
 
 
 def _trsm_l_blocked(U, B, block: int):
     """Solve U W = B (U upper): block backward substitution."""
-    n = U.shape[0]
-    W = jnp.zeros_like(B)
-    starts = list(range(0, n, block))
-    for k0 in reversed(starts):
-        k1 = min(k0 + block, n)
-        rhs = B[k0:k1] - U[k0:k1, k1:] @ W[k1:]
-        W = W.at[k0:k1].set(_solve_tri(U[k0:k1, k0:k1], rhs, lower=False))
-    return W
+    return solve_upper_looped(U, B, trans=False, block=block)
 
 
 def _row_sharded(mesh, M):
@@ -342,7 +314,7 @@ def dist_cholesky(mesh, B, block=None):
     sh = _row_sharded(mesh, B)
     Bm = jax.device_put(B, sh)
     blk = _panel(mesh, B.shape[0], block)
-    return _jit_blocked(_chol_blocked, blk, sh)(Bm)
+    return _jit_blocked(cholesky_blocked, blk, sh)(Bm)
 
 
 def dist_trsm_left_t(mesh, U, B, block=None):

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import I0
+
 
 def _band_mv_kernel(cur_ref, prev_ref, x_ref, o_ref, *, w: int, bm: int,
                     n: int):
@@ -60,10 +62,10 @@ def band_mv_pallas(band: jax.Array, x: jax.Array, w: int, bm: int = 256,
         functools.partial(_band_mv_kernel, w=w, bm=bm, n=n),
         grid=(n // bm,),
         in_specs=[
-            pl.BlockSpec((bm, wp1), lambda i: (i, 0)),
+            pl.BlockSpec((bm, wp1), lambda i: (i, I0)),
             # previous tile (clamped at the first step; masked in-kernel)
-            pl.BlockSpec((bm, wp1), lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec((bm, wp1), lambda i: (jnp.maximum(i - 1, 0), I0)),
+            pl.BlockSpec((n,), lambda i: (I0,)),
         ],
         out_specs=pl.BlockSpec((bm,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), band.dtype),

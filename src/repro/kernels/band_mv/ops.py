@@ -6,12 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from .kernel import band_mv_pallas
 from .ref import band_mv_ref, band_to_dense, dense_to_band
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("w", "bm", "force_interpret"))
@@ -19,7 +17,9 @@ def band_mv(band: jax.Array, x: jax.Array, w: int, bm: int = 128,
             force_interpret: bool | None = None) -> jax.Array:
     """y = A x for symmetric band A in (n, w+1) storage (zero-pads rows)."""
     n = band.shape[0]
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    if not dispatch.use_pallas(band.dtype, force=True):
+        return band_mv_ref(band, x)
+    interpret = dispatch.interpret(force_interpret)
     bm_ = min(bm, n)
     while n % bm_:
         bm_ -= 1

@@ -6,12 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from .kernel import gemm_pallas
 from .ref import gemm_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, b: int) -> int:
@@ -22,10 +20,13 @@ def _round_up(x: int, b: int) -> int:
                                              "force_interpret"))
 def gemm(A: jax.Array, B: jax.Array, bm: int = 128, bn: int = 128,
          bk: int = 128, force_interpret: bool | None = None) -> jax.Array:
-    """C = A @ B via the tiled Pallas kernel (zero-pads to tile multiples)."""
+    """C = A @ B via the tiled Pallas kernel (zero-pads to tile multiples);
+    operands the kernel cannot take (``kernels.dispatch``) get XLA's."""
     m, k = A.shape
     _, n = B.shape
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    if not dispatch.use_pallas(A.dtype, force=True):
+        return gemm_ref(A, B)
+    interpret = dispatch.interpret(force_interpret)
     bm_, bn_, bk_ = min(bm, _round_up(m, 8)), min(bn, _round_up(n, 8)), \
         min(bk, _round_up(k, 8))
     mp, np_, kp = _round_up(m, bm_), _round_up(n, bn_), _round_up(k, bk_)

@@ -14,12 +14,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from .kernel import house_panel_pallas
 from .ref import house_panel_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def house_panel(E: jax.Array, row_start,
@@ -33,12 +31,13 @@ def house_panel(E: jax.Array, row_start,
     triangular; Q = I - V T V^T. Pivots past the panel end (the rows < b
     tail panel) yield identity reflectors (tau = 0).
 
-    Dispatches to the Pallas kernel on TPU (or when ``force_kernel=True``,
-    using interpret mode off-TPU); otherwise the pure-jnp oracle. Rows are
-    padded to the sublane multiple internally.
+    Dispatches (``kernels.dispatch``) to the Pallas kernel on TPU when the
+    dtype and the VMEM-resident panel allow it (or when
+    ``force_kernel=True``, using interpret mode off-TPU); otherwise the
+    pure-jnp oracle. Rows are padded to the sublane multiple internally.
     """
-    use_kernel = force_kernel or _on_tpu()
-    if not use_kernel:
+    if not dispatch.use_pallas(E.dtype, vmem_bytes(*E.shape),
+                               force=force_kernel):
         if E.dtype == jnp.bfloat16:
             # mirror the kernel's fp32-accumulating bf16 path: reflector
             # norms/taus cancel too hard for bf16 arithmetic
@@ -49,10 +48,18 @@ def house_panel(E: jax.Array, row_start,
     pad = (-rows) % 8
     if pad:
         E = jnp.pad(E, ((0, pad), (0, 0)))
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    interpret = dispatch.interpret(force_interpret)
     rs = jnp.asarray(row_start, jnp.int32).reshape((1,))
     V, T = house_panel_pallas(E, rs, interpret=interpret)
     return V[:rows], T
 
 
-__all__ = ["house_panel", "house_panel_ref"]
+def vmem_bytes(rows: int, b: int) -> int:
+    """The kernel keeps the whole panel resident, and its reflector loop
+    is unrolled: about b + 4 live (rows, b) f32 values, each padded to the
+    128-lane tile. (Compiled for a v5e: a (4096, 16) panel asks for 40 MB
+    of the 16 MiB scoped VMEM and is refused; (2048, 16) compiles.)"""
+    return (b + 4) * (-(-rows // 8) * 8) * max(b, 128) * 4
+
+
+__all__ = ["house_panel", "house_panel_ref", "vmem_bytes"]

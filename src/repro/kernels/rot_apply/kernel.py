@@ -20,6 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import I0
+
+
 
 def _rot_apply_kernel(x0_ref, x1_ref, c_ref, s_ref, y0_ref, y1_ref):
     # bf16 tiles rotate in fp32 (VPU fma in the accumulator dtype) and
@@ -51,8 +54,8 @@ def rot_apply_pallas(x0: jax.Array, x1: jax.Array, c: jax.Array,
         in_specs=[
             pl.BlockSpec((bg, bl), lambda i, j: (i, j)),
             pl.BlockSpec((bg, bl), lambda i, j: (i, j)),
-            pl.BlockSpec((bg, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bg, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (i, I0)),
+            pl.BlockSpec((bg, 1), lambda i, j: (i, I0)),
         ],
         out_specs=[
             pl.BlockSpec((bg, bl), lambda i, j: (i, j)),

@@ -12,12 +12,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from .kernel import rot_apply_pallas
 from .ref import rot_apply_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def rot_apply(pairs: jax.Array, cs: jax.Array,
@@ -28,12 +26,12 @@ def rot_apply(pairs: jax.Array, cs: jax.Array,
     pairs: (G, 2, L) — G disjoint row pairs.
     cs:    (G, 2)    — (c, s) per pair, out0 = c*x0 + s*x1, out1 = -s*x0 + c*x1.
 
-    Dispatches to the Pallas kernel on TPU (or when ``force_kernel=True``,
-    using interpret mode off-TPU); otherwise the vectorized jnp fallback.
-    Shapes are padded to tile multiples internally.
+    Dispatches (``kernels.dispatch``) to the Pallas kernel on TPU for the
+    dtypes it takes (or when ``force_kernel=True``, using interpret mode
+    off-TPU); otherwise the vectorized jnp fallback. Shapes are padded to
+    tile multiples internally.
     """
-    use_kernel = force_kernel or _on_tpu()
-    if not use_kernel:
+    if not dispatch.use_pallas(pairs.dtype, force=force_kernel):
         if pairs.dtype == jnp.bfloat16:
             # fp32-accumulate the rotation (the kernel's bf16 path does
             # the same); the store casts back to bf16
@@ -55,7 +53,7 @@ def rot_apply(pairs: jax.Array, cs: jax.Array,
         x1 = jnp.pad(x1, ((0, gpad), (0, lpad)))
         c = jnp.pad(c, ((0, gpad), (0, 0)), constant_values=1.0)
         s = jnp.pad(s, ((0, gpad), (0, 0)))
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    interpret = dispatch.interpret(force_interpret)
     y0, y1 = rot_apply_pallas(x0, x1, c, s, bg=bg, bl=bl, interpret=interpret)
     return jnp.stack([y0[:G, :L], y1[:G, :L]], axis=1)
 

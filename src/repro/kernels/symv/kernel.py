@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: symmetric mat-vec reading only the UPPER triangle.
+"""Pallas TPU kernel: symmetric matrix times an (n, p) block, reading only
+the UPPER triangle (a symv is its p = 1 case).
 
 The paper's KE1 (CUBLAS/MAGMA DSYMV) is the hot loop of the Krylov solver. On
 TPU a symv is HBM-bandwidth-bound (2 flops per element read), so the win the
@@ -12,9 +13,10 @@ halving HBM traffic vs a dense gemv. The grid enumerates the nb(nb+1)/2
 upper-triangle tiles via scalar-prefetched (ib, jb) index arrays
 (PrefetchScalarGridSpec), row-major so y_up accumulates contiguously.
 
-VMEM budget per step: bm*bn*4 bytes (tile) + bn*4 + 2*bm*4; with the default
-bm = bn = 512 and f32 that is ~1 MiB << 16 MiB v5e VMEM, leaving room for
-double buffering. Tile dims are multiples of (8, 128) as the VPU/MXU want.
+VMEM budget per step: the (block, block) tile plus four (block, p) slices;
+with block = 512, p = 128 and f32 that is ~2 MiB << 16 MiB v5e VMEM,
+leaving room for double buffering. Tile dims are multiples of (8, 128) as
+the VPU/MXU want.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels.dispatch import I0
 from jax.experimental.pallas import tpu as pltpu
 
 
@@ -70,47 +74,14 @@ def _symv_kernel(ib, jb, a_ref, xj_ref, xi_ref, yu_ref, yl_ref):
         yl_ref[...] += dot(a.T, xi_ref[...])
 
 
+
+
 def triangle_indices(nb: int):
     """Row-major upper-triangle (i, j >= i) block index arrays."""
     pairs = [(i, j) for i in range(nb) for j in range(i, nb)]
     ib = np.asarray([p[0] for p in pairs], np.int32)
     jb = np.asarray([p[1] for p in pairs], np.int32)
     return ib, jb
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def symv_pallas(A: jax.Array, x: jax.Array, block: int = 512,
-                interpret: bool = True) -> jax.Array:
-    """y = A x for symmetric A, reading only the upper triangle of A.
-
-    Requires n % block == 0 (ops.py pads). Returns y (n,).
-    """
-    n = A.shape[0]
-    assert n % block == 0, (n, block)
-    nb = n // block
-    ib, jb = triangle_indices(nb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(len(ib),),
-        in_specs=[
-            pl.BlockSpec((block, block), lambda t, ib, jb: (ib[t], jb[t])),
-            pl.BlockSpec((block,), lambda t, ib, jb: (jb[t],)),
-            pl.BlockSpec((block,), lambda t, ib, jb: (ib[t],)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda t, ib, jb: (ib[t],)),
-            pl.BlockSpec((block,), lambda t, ib, jb: (jb[t],)),
-        ],
-    )
-    acc_t = jnp.float32 if A.dtype == jnp.bfloat16 else A.dtype
-    y_up, y_lo = pl.pallas_call(
-        _symv_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n,), acc_t)] * 2,
-        interpret=interpret,
-    )(jnp.asarray(ib), jnp.asarray(jb), A, x, x)
-    return (y_up + y_lo).astype(A.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -139,12 +110,12 @@ def symm_block_pallas(A: jax.Array, X: jax.Array, block: int = 512,
         grid=(len(ib),),
         in_specs=[
             pl.BlockSpec((block, block), lambda t, ib, jb: (ib[t], jb[t])),
-            pl.BlockSpec((block, p), lambda t, ib, jb: (jb[t], 0)),
-            pl.BlockSpec((block, p), lambda t, ib, jb: (ib[t], 0)),
+            pl.BlockSpec((block, p), lambda t, ib, jb: (jb[t], I0)),
+            pl.BlockSpec((block, p), lambda t, ib, jb: (ib[t], I0)),
         ],
         out_specs=[
-            pl.BlockSpec((block, p), lambda t, ib, jb: (ib[t], 0)),
-            pl.BlockSpec((block, p), lambda t, ib, jb: (jb[t], 0)),
+            pl.BlockSpec((block, p), lambda t, ib, jb: (ib[t], I0)),
+            pl.BlockSpec((block, p), lambda t, ib, jb: (jb[t], I0)),
         ],
     )
     acc_t = jnp.float32 if A.dtype == jnp.bfloat16 else A.dtype

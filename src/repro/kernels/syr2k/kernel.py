@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import I0
+
 
 def _syr2k_kernel(c_ref, vi_ref, wj_ref, wi_ref, vj_ref, o_ref, *, alpha,
                   acc_dtype):
@@ -48,10 +50,10 @@ def syr2k_pallas(C: jax.Array, V: jax.Array, W: jax.Array,
         grid=(nb, nb),
         in_specs=[
             pl.BlockSpec((bm, bm), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, I0)),
+            pl.BlockSpec((bm, k), lambda i, j: (j, I0)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, I0)),
+            pl.BlockSpec((bm, k), lambda i, j: (j, I0)),
         ],
         out_specs=pl.BlockSpec((bm, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), C.dtype),

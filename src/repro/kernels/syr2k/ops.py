@@ -6,12 +6,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from .kernel import syr2k_pallas
 from .ref import syr2k_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, b: int) -> int:
@@ -23,7 +21,9 @@ def syr2k(C: jax.Array, V: jax.Array, W: jax.Array, alpha: float = -1.0,
           bm: int = 256, force_interpret: bool | None = None) -> jax.Array:
     """C + alpha (V W^T + W V^T), padding n to the tile size."""
     n, k = V.shape
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    if not dispatch.use_pallas(C.dtype, force=True):
+        return syr2k_ref(C, V, W, alpha)
+    interpret = dispatch.interpret(force_interpret)
     bm_ = min(bm, _round_up(n, 8))
     np_ = _round_up(n, bm_)
     pad = np_ - n

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from repro.core.linalg_utils import gershgorin_bounds
 from repro.core.tridiag_eig import (_cluster_ids, _pivmin, bisect_eigenvalues,
                                     inverse_iteration)
+from repro.kernels import dispatch
+
 from .kernel import bisect_sturm_pallas, invit_pallas
 
 #: Sturm-scan unroll of the fused XLA path — measured sweet spot on host
@@ -40,12 +42,21 @@ from .kernel import bisect_sturm_pallas, invit_pallas
 SCAN_UNROLL = 16
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pad_up(k: int, mult: int) -> int:
     return k + (-k) % mult
+
+
+def bisect_vmem_bytes(n: int) -> int:
+    """The Sturm kernel keeps d and e^2 resident as (N, 1) f32 columns,
+    each padded to the 128-lane tile."""
+    return 2 * _pad_up(n, 8) * 128 * 4
+
+
+def invit_vmem_bytes(n: int, s: int) -> int:
+    """The inverse-iteration kernel keeps the (N, S) start block, its
+    result and four (N, S) scratch factors resident, plus d and e."""
+    N, S = _pad_up(n, 8), _pad_up(s, 128)
+    return (6 * N * S + 2 * N * 128) * 4
 
 
 # ------------------------------------------------------------ fused XLA --
@@ -72,12 +83,12 @@ def bisect_sturm(d: jax.Array, e: jax.Array, ks: jax.Array,
                  force_interpret: bool | None = None) -> jax.Array:
     """Eigenvalues at indices ``ks`` — Pallas kernel on TPU, unrolled XLA
     scan elsewhere. Both agree bitwise with ``bisect_sturm_ref``."""
-    use_kernel = force_kernel or _on_tpu()
-    if not use_kernel:
+    n, s = d.shape[0], ks.shape[0]
+    if not dispatch.use_pallas(d.dtype, bisect_vmem_bytes(n),
+                               force=force_kernel):
         return bisect_eigenvalues(d, e, ks, max_iters=max_iters,
                                   unroll=SCAN_UNROLL)
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
-    n, s = d.shape[0], ks.shape[0]
+    interpret = dispatch.interpret(force_interpret)
     N, S = _pad_up(n, 8), _pad_up(s, 128)
     lo0, hi0 = gershgorin_bounds(d, e)
     piv = _pivmin(d, e)
@@ -102,11 +113,11 @@ def invit_batched(d: jax.Array, e: jax.Array, lam: jax.Array,
                   force_interpret: bool | None = None) -> jax.Array:
     """Eigenvectors for SORTED shifts ``lam`` — Pallas kernel on TPU,
     the vmapped-scan LU elsewhere."""
-    use_kernel = force_kernel or _on_tpu()
-    if not use_kernel:
-        return inverse_iteration(d, e, lam, key, iters=iters)
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
     n, s = d.shape[0], lam.shape[0]
+    if not dispatch.use_pallas(d.dtype, invit_vmem_bytes(n, s),
+                               force=force_kernel):
+        return inverse_iteration(d, e, lam, key, iters=iters)
+    interpret = dispatch.interpret(force_interpret)
     N, S = _pad_up(n, 8), _pad_up(s, 128)
     scale = jnp.maximum(jnp.max(jnp.abs(d)),
                         jnp.max(jnp.abs(e)) if e.size else 0.0)
@@ -140,4 +151,5 @@ def tridiag_eig_kernel(d: jax.Array, e: jax.Array, ks: jax.Array,
 
 
 __all__ = ["tridiag_eig_batched", "tridiag_eig_kernel", "bisect_sturm",
-           "invit_batched", "SCAN_UNROLL"]
+           "invit_batched", "bisect_vmem_bytes", "invit_vmem_bytes",
+           "SCAN_UNROLL"]

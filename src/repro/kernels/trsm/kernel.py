@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import I0
+
 
 def _trsm_tile_upper_kernel(u_ref, b_ref, x_ref):
     """Solve U X = B for one (b, b) upper-triangular tile, RHS (b, s).
@@ -71,10 +73,10 @@ def trsm_tile(U: jax.Array, B: jax.Array, trans: bool = False,
         kern,
         grid=(1,),
         in_specs=[
-            pl.BlockSpec((b, b), lambda i: (0, 0)),
-            pl.BlockSpec((b, s), lambda i: (0, 0)),
+            pl.BlockSpec((b, b), lambda i: (I0, I0)),
+            pl.BlockSpec((b, s), lambda i: (I0, I0)),
         ],
-        out_specs=pl.BlockSpec((b, s), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((b, s), lambda i: (I0, I0)),
         out_shape=jax.ShapeDtypeStruct((b, s), B.dtype),
         interpret=interpret,
     )(U, B)

@@ -12,13 +12,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
+
 from ..gemm.ops import gemm
 from .kernel import trsm_tile
 from .ref import trsm_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("trans", "block",
@@ -30,7 +28,10 @@ def trsm(U: jax.Array, B: jax.Array, trans: bool = False, block: int = 128,
     if squeeze:
         B = B[:, None]
     n, s = B.shape
-    interpret = (not _on_tpu()) if force_interpret is None else force_interpret
+    if not dispatch.use_pallas(U.dtype, force=True):
+        X = trsm_ref(U, B, trans=trans)
+        return X[:, 0] if squeeze else X
+    interpret = dispatch.interpret(force_interpret)
     block = min(block, n)
     X = jnp.zeros_like(B)
     blocks = [(k0, min(k0 + block, n)) for k0 in range(0, n, block)]

@@ -17,42 +17,25 @@ and the StageCost cross-check against ``analysis.variant_model``.
 Writes ``artifacts/AUDIT.json`` and exits nonzero on any budget, dtype
 or cross-check violation (warnings don't fail). Defaults to 2 forced
 host devices so the distributed contracts are audited with real
-collectives; ``--devices 1`` skips the mesh entries.
+collectives; ``--devices 1`` skips the mesh entries. A CPU tool: it
+lowers programs and never runs them.
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
 import sys
 
+import jax
 
-def _early_device_count() -> int:
-    """--devices must take effect before jax is imported (XLA_FLAGS)."""
-    argv = sys.argv
-    for i, a in enumerate(argv):
-        if a == "--devices" and i + 1 < len(argv):
-            return int(argv[i + 1])
-        if a.startswith("--devices="):
-            return int(a.split("=", 1)[1])
-    return 2     # audit the distributed contracts by default
-
-
-_n_dev = _early_device_count()
-if _n_dev > 1:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n_dev}").strip()
-
-import argparse  # noqa: E402
-import json      # noqa: E402
-
-import jax       # noqa: E402
-
-jax.config.update("jax_enable_x64", True)
-
-from repro.analysis.static_audit import (        # noqa: E402
+from repro.analysis.static_audit import (
     AuditSpec, all_ok, check_all, check_entry, crosscheck_stagecosts,
     entries, errors, get_entry, lint_pallas_profiles, lint_reports,
     lint_signature_parity, register_all)
+from repro.launch.runtime import force_host_devices
+
+jax.config.update("jax_enable_x64", True)
 
 
 def run_audit(quick: bool = False, entry: str | None = None,
@@ -154,7 +137,7 @@ def main(argv=None) -> int:
         description="static HLO/jaxpr budget audit of every solver path")
     ap.add_argument("--devices", type=int, default=2,
                     help="forced host devices (>=2 audits the mesh "
-                         "contracts; handled before jax import)")
+                         "contracts; CPU only)")
     ap.add_argument("--quick", action="store_true",
                     help="only the 'quick'-tagged entries (CI fast lane)")
     ap.add_argument("--entry", default=None,
@@ -164,6 +147,7 @@ def main(argv=None) -> int:
     ap.add_argument("-o", "--out", default="artifacts/AUDIT.json",
                     help="artifact path ('' disables writing)")
     args = ap.parse_args(argv)
+    force_host_devices(args.devices)
 
     payload = run_audit(quick=args.quick, entry=args.entry)
     if args.out and args.entry is None:

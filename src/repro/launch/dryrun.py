@@ -1,26 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^^ MUST precede every other import (jax locks the device count on first
-# init). 512 host devices let jax.make_mesh build the production meshes.
-
-import argparse          # noqa: E402
-import json              # noqa: E402
-import re                # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
-
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro.configs import ARCH_IDS, arch_shapes, get_config  # noqa: E402
-from repro.launch.mesh import make_production_mesh           # noqa: E402
-from repro.launch.specs import (prefill_specs, serve_specs,   # noqa: E402
-                                train_specs)
-from repro.models.config import shape_by_name                 # noqa: E402
-from repro.train.optimizer import OptimizerConfig             # noqa: E402
-from repro.train.train_step import (make_serve_step,          # noqa: E402
-                                    make_train_step)
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces (and persists under artifacts/dryrun/):
@@ -34,14 +11,24 @@ Shape semantics per the assignment: train_4k lowers train_step;
 prefill_32k lowers the full-sequence prefill; decode_32k / long_500k lower
 serve_step (ONE new token against a seq_len KV cache).
 """
+import argparse
+import json
+import re
+import time
+import traceback
 
-try:                                  # jax >= 0.5 ambient-mesh API
-    _set_mesh = jax.set_mesh
-except AttributeError:                # 0.4.x: specs carry NamedShardings,
-    import contextlib                 # no ambient mesh needed for .lower()
+import jax
+import jax.numpy as jnp
 
-    def _set_mesh(_mesh):
-        return contextlib.nullcontext()
+from repro.configs import ARCH_IDS, arch_shapes, get_config
+from repro.launch.mesh import make_production_mesh
+from repro.launch.runtime import force_host_devices
+from repro.launch.specs import (prefill_specs, serve_specs,
+                                train_specs)
+from repro.models.config import shape_by_name
+from repro.train.optimizer import OptimizerConfig
+from repro.train.train_step import (make_serve_step,
+                                    make_train_step)
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
                 "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8, "c64": 8}
@@ -90,14 +77,14 @@ def lower_cell(mesh, arch: str, shape_name: str,
     if shape.kind == "train":
         state_specs, batch_specs = train_specs(mesh, cfg, shape)
         step = make_train_step(cfg, OptimizerConfig())
-        with _set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step).lower(state_specs, batch_specs)
         return lowered, "train_step"
     if shape.kind == "prefill":
         param_specs, batch_specs = prefill_specs(mesh, cfg, shape)
         from repro.train.train_step import make_prefill
         pf = make_prefill(cfg)
-        with _set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if cfg.encoder_decoder:
                 lowered = jax.jit(pf).lower(param_specs,
                                             batch_specs["tokens"],
@@ -110,7 +97,7 @@ def lower_cell(mesh, arch: str, shape_name: str,
     param_specs, token_specs, state_specs = serve_specs(
         mesh, cfg, shape, fsdp_params=(serve_sharding == "fsdp"))
     serve = make_serve_step(cfg)
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(serve).lower(param_specs, token_specs, state_specs)
     return lowered, "serve_step"
 
@@ -182,6 +169,8 @@ def main() -> None:
     ap.add_argument("--serve-dtype", default=None,
                     choices=[None, "bfloat16"])
     args = ap.parse_args()
+    # 512 host devices let make_production_mesh build the production meshes
+    force_host_devices(512)
 
     archs = list(ARCH_IDS) if args.arch == "all" else [args.arch]
     meshes = []

@@ -1,24 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^^ MUST precede every other import (see dryrun.py).
-
-import argparse          # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.core.lanczos import lanczos_solve_jit               # noqa: E402
-from repro.core.operators import ExplicitC                      # noqa: E402
-from repro.dist.sharded_la import (dist_cholesky, dist_gemm,  # noqa: E402
-                                   dist_gemm_rs, dist_symv, dist_symv_rs,
-                                   dist_trsm_left_t)
-from repro.launch.dryrun import (_set_mesh,                   # noqa: E402
-                                 parse_collective_bytes)
-from repro.launch.mesh import make_production_mesh            # noqa: E402
-
 """Eigensolver-side multi-pod dry-run: lowers the PAPER's pipelines on the
 production meshes (the LM dry-run lives in dryrun.py).
 
@@ -29,6 +8,22 @@ Stages lowered, mirroring Table 1 of the paper:
   BT1  dist_trsm              (back-transform)
 Artifacts (cost/memory/collectives) feed §Roofline for the paper-side rows.
 """
+import argparse
+import json
+import time
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.core.lanczos import lanczos_solve_jit
+from repro.core.operators import ExplicitC
+from repro.dist.sharded_la import (dist_cholesky, dist_gemm,
+                                   dist_gemm_rs, dist_symv, dist_symv_rs,
+                                   dist_trsm_left_t)
+from repro.launch.dryrun import parse_collective_bytes
+from repro.launch.mesh import make_production_mesh
+from repro.launch.runtime import force_host_devices
 
 
 def run(mesh, mesh_name: str, n: int, s: int, outdir: str,
@@ -79,7 +74,7 @@ def run(mesh, mesh_name: str, n: int, s: int, outdir: str,
         rec = {"stage": name, "mesh": mesh_name, "n": n, "s": s,
                "status": "ok"}
         try:
-            with _set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(fn).lower(*specs)
             compiled = lowered.compile()
             from repro.analysis.roofline import cost_analysis_dict
@@ -123,6 +118,7 @@ def main() -> None:
                     choices=["single", "multi", "both"])
     ap.add_argument("--outdir", default="artifacts/eigen_dryrun")
     args = ap.parse_args()
+    force_host_devices(512)      # the production meshes, on host devices
 
     n_fail = 0
     if args.mesh in ("single", "both"):

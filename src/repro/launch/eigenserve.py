@@ -13,39 +13,19 @@ device mesh via ``--mesh``/``--devices``).
 """
 from __future__ import annotations
 
-import os
-import sys
+import argparse
+import json
+import time
 
+import jax
+import numpy as np
 
-def _early_device_count() -> int | None:
-    """--devices must take effect before jax is imported (XLA_FLAGS)."""
-    argv = sys.argv
-    for i, a in enumerate(argv):
-        if a == "--devices" and i + 1 < len(argv):
-            return int(argv[i + 1])
-        if a.startswith("--devices="):
-            return int(a.split("=", 1)[1])
-    return None
-
-
-_n_dev = _early_device_count()
-if _n_dev:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n_dev}").strip()
-
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
+from repro.data.problems import dft_like, md_like
+from repro.dist.partitioning import make_mesh
+from repro.launch.runtime import enable_compile_cache, force_host_devices
+from repro.serve.eigen_engine import EigenEngine
 
 jax.config.update("jax_enable_x64", True)
-
-import numpy as np  # noqa: E402
-
-from repro.data.problems import dft_like, md_like        # noqa: E402
-from repro.serve.eigen_engine import EigenEngine          # noqa: E402
 
 
 def _parse_mesh(spec: str | None):
@@ -54,7 +34,7 @@ def _parse_mesh(spec: str | None):
     dims = tuple(int(x) for x in spec.lower().split("x"))
     if len(dims) != 2:
         raise SystemExit(f"--mesh wants DATAxMODEL, e.g. 4x2; got {spec!r}")
-    return jax.make_mesh(dims, ("data", "model"))
+    return make_mesh(dims, ("data", "model"))
 
 
 def request_stream(kinds, shapes, n_requests: int, seed: int,
@@ -107,6 +87,8 @@ def main() -> None:
                          "it is dead-lettered")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    force_host_devices(args.devices)
+    enable_compile_cache()
 
     shapes = [int(x) for x in args.bucket_shapes.split(",") if x]
     kinds = ["md", "dft"] if args.stream == "mixed" else [args.stream]

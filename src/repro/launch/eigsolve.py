@@ -17,39 +17,19 @@ table are printed in the payload under ``router``).
 """
 from __future__ import annotations
 
-import os
-import sys
+import argparse
+import json
 
+import jax
+import numpy as np
 
-def _early_device_count() -> int | None:
-    """--devices must take effect before jax is imported (XLA_FLAGS)."""
-    argv = sys.argv
-    for i, a in enumerate(argv):
-        if a == "--devices" and i + 1 < len(argv):
-            return int(argv[i + 1])
-        if a.startswith("--devices="):
-            return int(a.split("=", 1)[1])
-    return None
-
-
-_n_dev = _early_device_count()
-if _n_dev:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n_dev}").strip()
-
-import argparse  # noqa: E402
-import json      # noqa: E402
-
-import jax       # noqa: E402
+from repro.core import solve
+from repro.core.residuals import accuracy_report
+from repro.data.problems import dft_like, md_like
+from repro.dist.partitioning import make_mesh
+from repro.launch.runtime import enable_compile_cache, force_host_devices
 
 jax.config.update("jax_enable_x64", True)
-
-import numpy as np  # noqa: E402
-
-from repro.core import solve                      # noqa: E402
-from repro.core.residuals import accuracy_report  # noqa: E402
-from repro.data.problems import dft_like, md_like  # noqa: E402
 
 
 def _parse_mesh(spec: str | None):
@@ -59,7 +39,7 @@ def _parse_mesh(spec: str | None):
     dims = tuple(int(x) for x in spec.lower().split("x"))
     if len(dims) != 2:
         raise SystemExit(f"--mesh wants DATAxMODEL, e.g. 4x2; got {spec!r}")
-    return jax.make_mesh(dims, ("data", "model"))
+    return make_mesh(dims, ("data", "model"))
 
 
 def main() -> None:
@@ -100,8 +80,8 @@ def main() -> None:
                          "variant (or --variant auto, restricted to those "
                          "two) through the repro.dist distributed pipeline")
     ap.add_argument("--devices", type=int, default=None,
-                    help="force N host-platform devices (set before the "
-                         "jax import; pairs with --mesh on CPU)")
+                    help="force N host-platform devices (CPU only; pairs "
+                         "with --mesh)")
     ap.add_argument("--on-failure", choices=["recover", "warn", "ignore"],
                     default="warn",
                     help="degradation-ladder policy (resilience.recovery): "
@@ -113,6 +93,8 @@ def main() -> None:
                          "--on-failure recover")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    force_host_devices(args.devices)
+    enable_compile_cache()
 
     mesh = _parse_mesh(args.mesh)
     if mesh is not None and args.variant not in ("KE", "TT", "auto"):

@@ -6,14 +6,14 @@ any jax import).
 """
 from __future__ import annotations
 
-import jax
+from repro.dist.partitioning import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds the 2-pod leading axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *,
@@ -21,5 +21,5 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2, *,
     """Small mesh for in-CI multi-device tests (subprocesses set their own
     --xla_force_host_platform_device_count)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

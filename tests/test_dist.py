@@ -169,7 +169,8 @@ def test_partitioning_rules_shape_aware():
                                              decode_state_shardings,
                                              batch_shardings)
         from repro.models.model import init_params, init_decode_state
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = smoke_config("qwen2-moe-a2.7b")
         shapes = jax.eval_shape(functools.partial(init_params, cfg=cfg),
                                 jax.random.PRNGKey(0))
@@ -208,7 +209,8 @@ def test_sharded_la_multidevice():
         import numpy as np
         from repro.dist.sharded_la import (dist_symv, dist_gemm, dist_gemm_rs,
                                            dist_cholesky, dist_trsm_left_t)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         n = 64
         key = jax.random.PRNGKey(0)
         M = jax.random.normal(key, (n, n), jnp.float64)
@@ -248,7 +250,8 @@ _TT_PARITY_TEMPLATE = """
     from repro.data.problems import md_like
     from repro.core import solve
     from repro.dist.eigensolver import solve_tt_distributed
-    mesh = jax.make_mesh({mesh_shape}, ("data", "model"))
+    from repro.dist.partitioning import make_mesh
+    mesh = make_mesh({mesh_shape}, ("data", "model"))
     prob = md_like({n})
     ref = solve(prob.A, prob.B, {s}, variant="TT", band_width={w})
     evals, X = solve_tt_distributed(mesh, prob.A, prob.B, {s},
@@ -303,7 +306,8 @@ def test_distributed_tt1_fused_sweep_two_device():
         from repro.core.sbr import reduce_to_band
         from repro.dist import eigensolver as de
         # data=2: the row collectives (all_gather/psum) are real, not no-ops
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((2, 1), ("data", "model"))
         n, w = 32, 4
         M = jax.random.normal(jax.random.PRNGKey(3), (n, n), jnp.float64)
         C = 0.5 * (M + M.T)
@@ -377,8 +381,9 @@ _INVERT_PARITY_TEMPLATE = """
     from repro.data.problems import md_like
     from repro.core import solve
     from repro.core.residuals import accuracy_report
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
-    prob = md_like(48)  # A SPD: the inverse-pair trick is valid
+    from repro.dist.partitioning import make_mesh
+    mesh = make_mesh({mesh_shape}, ("data", "model"))
+    prob = md_like({n})  # A SPD: the inverse-pair trick is valid
     variant = {variant!r}
     ref = solve(prob.A, prob.B, 4, variant=variant, invert=True,
                 band_width=4, max_restarts=300)
@@ -400,12 +405,13 @@ _INVERT_PARITY_TEMPLATE = """
 """
 
 
-def _run_invert_parity(variant):
+def _run_invert_parity(variant, n=48, mesh_shape=(1, 2)):
     """invert=True combined with mesh= dispatch: the distributed KE/TT
     paths return through ``_finalize``'s inverse-pair epilogue (1/lam,
     re-sort, b_normalize against the original B). Parity against the local
     variant on a 2-device mesh — previously untested."""
-    code = textwrap.dedent(_INVERT_PARITY_TEMPLATE.format(variant=variant))
+    code = textwrap.dedent(_INVERT_PARITY_TEMPLATE.format(
+        variant=variant, n=n, mesh_shape=mesh_shape))
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, cwd=os.path.dirname(
@@ -415,6 +421,13 @@ def _run_invert_parity(variant):
 
 def test_distributed_invert_parity_two_device_ke():
     _run_invert_parity("KE")
+
+
+def test_distributed_ke_pads_uneven_n_two_device():
+    """An n that does not tile the mesh (47 over two row shards) is padded
+    — A with zeros, B with an identity block — and solves to the same
+    eigenpairs as the local KE."""
+    _run_invert_parity("KE", n=47, mesh_shape=(2, 1))
 
 
 @pytest.mark.slow
@@ -455,7 +468,8 @@ def test_distributed_ke_collective_and_dispatch_budget_two_device():
         n, s, p, m = spec.n, spec.s, spec.p, spec.m
         prob = md_like(n)
         for shape in ((1, 2), (2, 1)):
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            from repro.dist.partitioning import make_mesh
+            mesh = make_mesh(shape, ("data", "model"))
             # 1. the registered budget contract, on this orientation
             register_all(spec, mesh=mesh)
             rep = check_entry(get_entry("dist/ke_restart_program"))
@@ -469,7 +483,6 @@ def test_distributed_ke_collective_and_dispatch_budget_two_device():
                 mesh, prob.A, prob.B, s=s, m=m, p=p, tol=1e-9,
                 filter_degree=8, invert=True, return_info=True)
             assert info["converged"], info
-            assert info["fused"], info
             assert de.dispatch_count() <= ke_dispatch_budget(
                 info["n_restart"]), (de.dispatch_count(), info)
             np.testing.assert_allclose(np.asarray(evals),
@@ -511,7 +524,8 @@ def test_distributed_tt3_spectrum_partition_two_device():
         from repro.core.tridiag_eig import eigh_tridiag_selected
         from repro.data.problems import md_like
         from repro.dist import eigensolver as de
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((2, 1), ("data", "model"))
         n = 48
         kd, ke = jax.random.split(jax.random.PRNGKey(0))
         d = jax.random.normal(kd, (n,), jnp.float64)
@@ -587,7 +601,8 @@ def test_distributed_ke_pipeline_end_to_end():
         import numpy as np
         from repro.data.problems import md_like
         from repro.dist.eigensolver import solve_ke_distributed
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         prob = md_like(64)
         evals, X, info = solve_ke_distributed(mesh, prob.A, prob.B, s=4,
                                               m=24, tol=1e-9,

@@ -348,7 +348,8 @@ _PREEMPT_DRILL = textwrap.dedent("""
     from repro.resilience.faults import SimulatedPreemption
 
     prob = md_like(48, key=jax.random.PRNGKey(5))
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    from repro.dist.partitioning import make_mesh
+    mesh = make_mesh((2, 1), ("data", "model"))
     kw = dict(s=4, p=4, m=8, invert=True, max_restarts=200,
               return_info=True)
 
@@ -366,7 +367,8 @@ _PREEMPT_DRILL = textwrap.dedent("""
             print("PREEMPTED_AT", e.at_restart)
         # one host lost: resume from the checkpoint on the shrunken mesh
         plan = plan_remesh(1, 1)
-        mesh_small = jax.make_mesh(plan.new_shape, ("data", "model"))
+        from repro.dist.partitioning import make_mesh
+        mesh_small = make_mesh(plan.new_shape, ("data", "model"))
         lam2, _, info2 = solve_ke_distributed(
             mesh_small, prob.A, prob.B, checkpoint_dir=ckdir,
             resume=True, **kw)

@@ -248,10 +248,10 @@ def test_default_method_autodetects_backend(monkeypatch):
                         or real_kernel(*a, force_interpret=True, **k))
 
     d, e = _rand_tridiag(16, jax.random.PRNGKey(3))
-    monkeypatch.setattr(te, "default_tridiag_method", lambda: "batched")
+    monkeypatch.setattr(te, "default_tridiag_method", lambda *a: "batched")
     te.eigh_tridiag_selected(d, e, jnp.arange(3))
     assert calls == ["batched"]
-    monkeypatch.setattr(te, "default_tridiag_method", lambda: "kernel")
+    monkeypatch.setattr(te, "default_tridiag_method", lambda *a: "kernel")
     te.eigh_tridiag_selected(d, e, jnp.arange(3))
     assert calls == ["batched", "kernel"]
 
