@@ -16,7 +16,6 @@ Every stage is individually jitted and timed (paper Tables 2/6 keys).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Dict
@@ -32,6 +31,7 @@ from repro.resilience.recovery import (SolverError, cholesky_shift_taus,
 
 from .back_transform import back_transform_generalized
 from .cholesky import cholesky_blocked, cholesky_upper, diag_shifted
+from .instrument import counting, fetch, span, timed
 from .lanczos import default_subspace, lanczos_solve
 from .operators import ExplicitC, ImplicitC
 from .precision import (compute_dtype, ensure_strong, exact_matmuls,
@@ -52,16 +52,6 @@ class GSyEigResult:
     X: jax.Array                     # (n, s) B-orthonormal eigenvectors
     stage_times: Dict[str, float] = field(default_factory=dict)
     info: Dict[str, Any] = field(default_factory=dict)
-
-
-def _timed(times: Dict[str, float], key: str):
-    def wrap(fn, *args, **kwargs):
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        times[key] = times.get(key, 0.0) + (time.perf_counter() - t0)
-        return out
-    return wrap
 
 
 # module-level jitted stages (cached across driver calls with equal shapes).
@@ -290,14 +280,14 @@ def _solve_once(
     # ---- GS1: B = U^T U --------------------------------------------------
     # the factor's health sentinel is fused into the same program (zero
     # extra dispatches); fetching the scalar verdict is a transfer the
-    # _timed block_until_ready already paid for
+    # stage timer's wait already paid for
     Bg = faults.poison_stage("GS1", B)
     chol_stage = (partial(_jit_chol_blocked, block=block)
                   if gs1 == "blocked" else _jit_chol)
-    U, gs1_ok, _ = _timed(times, "GS1")(chol_stage, Bg)
-    gs1_ok = bool(jax.device_get(gs1_ok))
+    U, gs1_ok, _ = timed(times, "GS1", chol_stage, Bg)
+    gs1_ok = bool(fetch(gs1_ok))
     if not gs1_ok and on_failure != "ignore":
-        if not host_finite(Bg):
+        if not host_finite(fetch(Bg)):
             stage_health["GS1"] = False
             raise SolverError(
                 "non-finite B entering GS1 (Cholesky)", stage="GS1",
@@ -312,9 +302,9 @@ def _solve_once(
         # run as ONE vmapped dispatch with a single fetch of the
         # per-rung verdicts, so an exhausted ladder stays cheap
         taus = cholesky_shift_taus()
-        Us, oks = _timed(times, "GS1")(
-            _jit_chol_ladder, Bg, jnp.asarray(taus, dtype=Bg.dtype))
-        oks = [bool(x) for x in jax.device_get(oks)]
+        Us, oks = timed(times, "GS1", _jit_chol_ladder, Bg,
+                        jnp.asarray(taus, dtype=Bg.dtype))
+        oks = [bool(x) for x in fetch(oks)]
         for i, tau in enumerate(taus):
             if oks[i]:
                 recovery.append(rung("cholesky_shift", "GS1", "recovered",
@@ -342,11 +332,11 @@ def _solve_once(
     if variant in ("TD", "TT", "KE"):
         Ag = faults.poison_stage("GS2", A)
         if gs2 == "sygst":
-            C, gs2_ok = _timed(times, "GS2")(_jit_gs2_sygst, Ag, U,
-                                             block=block)
+            C, gs2_ok = timed(times, "GS2", _jit_gs2_sygst, Ag, U,
+                              block=block)
         else:
-            C, gs2_ok = _timed(times, "GS2")(_jit_gs2_trsm, Ag, U)
-        gs2_ok = bool(jax.device_get(gs2_ok))
+            C, gs2_ok = timed(times, "GS2", _jit_gs2_trsm, Ag, U)
+        gs2_ok = bool(fetch(gs2_ok))
         stage_health["GS2"] = gs2_ok
         if not gs2_ok and on_failure != "ignore":
             raise SolverError(
@@ -366,13 +356,13 @@ def _solve_once(
         if variant == "TD":
             Cw = faults.poison_stage("TD1", Cw)
             if td1 == "blocked":
-                res = _timed(times, "TD1")(_jit_td1_blocked, Cw, panel=32)
+                res = timed(times, "TD1", _jit_td1_blocked, Cw, panel=32)
             else:
-                res = _timed(times, "TD1")(_jit_td1, Cw)
+                res = timed(times, "TD1", _jit_td1, Cw)
             # host-side sentinel on the small (n,)/(n-1,) tridiagonal
             # outputs the TD2 stage fetches anyway — zero dispatches (a
             # wrapping jit would break the composite stage's own timing)
-            stage_health["TD1"] = host_finite(res.d, res.e)
+            stage_health["TD1"] = host_finite(*fetch((res.d, res.e)))
             if not stage_health["TD1"] and on_failure != "ignore":
                 raise SolverError(
                     "non-finite tridiagonal after TD1", stage="TD1",
@@ -381,10 +371,10 @@ def _solve_once(
                          "(demoted-stage overflow or upstream NaN)",
                     recovery=recovery,
                 health=verdict_from_stages(stage_health).as_json_dict())
-            lam, Z = _timed(times, "TD2")(
-                eigh_tridiag_selected, res.d.astype(jnp.float64),
-                res.e.astype(jnp.float64), ks, key)
-            Y = _timed(times, "TD3")(_jit_td3, res, Z.astype(cdtype))
+            lam, Z = timed(times, "TD2", eigh_tridiag_selected,
+                           res.d.astype(jnp.float64),
+                           res.e.astype(jnp.float64), ks, key)
+            Y = timed(times, "TD3", _jit_td3, res, Z.astype(cdtype))
         else:
             # TT1 split: the sweep is ONE compiled program (reduce_to_band
             # is internally jitted); record the ladder choice + dispatch
@@ -392,12 +382,12 @@ def _solve_once(
             Cw = faults.poison_stage("TT1", Cw)
             n_chunks = default_n_chunks(n, band_width)
             d0 = _sbr.dispatch_count()
-            band = _timed(times, "TT1")(reduce_to_band, Cw, w=band_width,
-                                        n_chunks=n_chunks)
+            band = timed(times, "TT1", reduce_to_band, Cw, w=band_width,
+                         n_chunks=n_chunks)
             info["tt1"] = {"n_chunks": int(n_chunks),
                            "dispatches": int(_sbr.dispatch_count() - d0)}
             # host sentinel on the (w+1, n) band the chase consumes
-            stage_health["TT1"] = host_finite(band.Wb)
+            stage_health["TT1"] = host_finite(fetch(band.Wb))
             if not stage_health["TT1"] and on_failure != "ignore":
                 raise SolverError(
                     "non-finite band matrix after the TT1 sweep",
@@ -406,8 +396,8 @@ def _solve_once(
                          "(demoted-stage overflow or upstream NaN)",
                     recovery=recovery,
                 health=verdict_from_stages(stage_health).as_json_dict())
-            chase = _timed(times, "TT2")(band_chase, band.Wb, band_width)
-            stage_health["TT2"] = host_finite(chase.d, chase.e)
+            chase = timed(times, "TT2", band_chase, band.Wb, band_width)
+            stage_health["TT2"] = host_finite(*fetch((chase.d, chase.e)))
             if not stage_health["TT2"] and on_failure != "ignore":
                 raise SolverError(
                     "non-finite tridiagonal after the TT2 chase",
@@ -415,11 +405,11 @@ def _solve_once(
                     hint="the rotation wavefront hit non-finite band "
                          "entries", recovery=recovery,
                     health=verdict_from_stages(stage_health).as_json_dict())
-            lam, Z = _timed(times, "TT3")(
-                eigh_tridiag_selected, chase.d.astype(jnp.float64),
-                chase.e.astype(jnp.float64), ks, key)
-            Y = _timed(times, "TT4")(_jit_tt4, chase, band.Q1,
-                                     Z.astype(cdtype), w=band_width)
+            lam, Z = timed(times, "TT3", eigh_tridiag_selected,
+                           chase.d.astype(jnp.float64),
+                           chase.e.astype(jnp.float64), ks, key)
+            Y = timed(times, "TT4", _jit_tt4, chase, band.Q1,
+                      Z.astype(cdtype), w=band_width)
         Y = Y.astype(jnp.float64)
     else:
         arp_which = "SA" if want_small else "LA"
@@ -434,20 +424,18 @@ def _solve_once(
         elif p > 1 and m % p:
             m = -(-m // p) * p          # block-align a user-supplied m
         tol, max_restarts = faults.force_nonconverge(tol, max_restarts)
-        t0 = time.perf_counter()
-        lres = lanczos_solve(op, s, which=arp_which, m=m, tol=tol,
-                             max_restarts=max_restarts, key=key,
-                             use_kernel=use_kernel, p=p,
-                             filter_degree=filter_degree,
-                             compute_dtype=cdtype if demoted else None)
-        jax.block_until_ready(lres.evecs)
-        times[f"{prefix}_iter"] = time.perf_counter() - t0
+        lres = timed(times, f"{prefix}_iter", lanczos_solve, op, s,
+                     which=arp_which, m=m, tol=tol,
+                     max_restarts=max_restarts, key=key,
+                     use_kernel=use_kernel, p=p,
+                     filter_degree=filter_degree,
+                     compute_dtype=cdtype if demoted else None)
         # plain-Python payloads only: info must survive json.dump in the
         # benchmark scripts (a jax array here broke them)
         info.update(n_matvec=int(lres.n_matvec), n_restart=int(lres.n_restart),
                     converged=bool(lres.converged),
                     resid_bounds=[float(r) for r in
-                                  jnp.asarray(lres.resid_bounds)])
+                                  fetch(lres.resid_bounds)])
         stage_health[f"{prefix}_iter"] = bool(lres.healthy)
         if not lres.healthy and on_failure != "ignore":
             raise SolverError(
@@ -470,7 +458,7 @@ def _solve_once(
         lam, Y = lam[order], Y[:, order]
 
     # ---- BT1: X = U^{-1} Y ----------------------------------------------
-    X = _timed(times, "BT1")(_jit_bt1, U, Y)
+    X = timed(times, "BT1", _jit_bt1, U, Y)
 
     info["_stage_health"] = stage_health
     return _finalize(lam, X, A_orig, B_orig, which_orig, invert, times,
@@ -484,20 +472,19 @@ def _finalize(lam, X, A_orig, B_orig, which_orig: str, invert: bool,
     inverse-pair trick, refine against the original fp64 pencil when
     asked, and total the stage timings."""
     if invert:
-        lam = 1.0 / lam
-        order = jnp.argsort(lam)
-        lam, X = lam[order], X[:, order]
-        # the inverse-pair solve returns A-orthonormal vectors; renormalize
-        # each column to unit B-norm for the original problem's metric
-        from .residuals import b_normalize
-        X = b_normalize(X, B_orig)
+        with span("finalize"):
+            lam = 1.0 / lam
+            order = jnp.argsort(lam)
+            lam, X = lam[order], X[:, order]
+            # the inverse-pair solve returns A-orthonormal vectors;
+            # renormalize each column to unit B-norm for the original
+            # problem's metric
+            from .residuals import b_normalize
+            X = b_normalize(X, B_orig)
 
     if refine_cfg is not None:
-        t0 = time.perf_counter()
-        lam, X, rinfo = refine_eigenpairs(
-            A_orig, B_orig, lam, X, which=which_orig, **refine_cfg)
-        jax.block_until_ready(X)
-        times["RF"] = time.perf_counter() - t0
+        lam, X, rinfo = timed(times, "RF", refine_eigenpairs, A_orig, B_orig,
+                              lam, X, which=which_orig, **refine_cfg)
         info["refinement"] = rinfo
 
     times["Tot."] = float(sum(v for k, v in times.items() if k != "Tot."))
@@ -554,10 +541,12 @@ def solve(
         retries); the health verdict is still recorded.
 
     Every solve carries ``info['health']`` (per-stage verdicts, JSON-
-    clean) and ``info['recovery']`` (the rungs taken, possibly empty).
+    clean), ``info['recovery']`` (the rungs taken, possibly empty) and
+    ``info['counters']`` (``core.instrument``'s host syncs, dispatches
+    and lowered programs of the whole call, ints). The call is the
+    profiler span ``repro.solve``, parent of every stage's span.
     """
     validate_on_failure(on_failure)
-    recovery: list = []
     kw: Dict[str, Any] = dict(
         variant=variant, which=which, invert=invert, gs2=gs2, gs1=gs1,
         td1=td1, band_width=band_width, block=block, m=m, tol=tol,
@@ -566,6 +555,18 @@ def solve(
         krylov_block=krylov_block, filter=filter, precision=precision,
         refine=refine, refine_tol=refine_tol,
         refine_max_steps=refine_max_steps)
+    with counting() as counters, span("solve", n=int(A.shape[0]), s=int(s),
+                                      variant=variant):
+        res = _contained(A, B, s, kw, on_failure, max_retries)
+    res.info["counters"] = counters
+    return res
+
+
+def _contained(A, B, s: int, kw: Dict[str, Any], on_failure: str,
+               max_retries: int) -> GSyEigResult:
+    """``solve``'s body: the attempts and the degradation ladder."""
+    recovery: list = []
+    max_restarts, precision = kw["max_restarts"], kw["precision"]
 
     def attempt(attempt_kw):
         res = _solve_once(A, B, s, on_failure=on_failure,
@@ -573,7 +574,7 @@ def solve(
         stages = res.info.pop("_stage_health", {})
         # final output sentinel: host-side on the (s,)/(n, s) results the
         # caller fetches anyway — zero extra dispatches
-        out_ok = host_finite(res.evals, res.X)
+        out_ok = host_finite(*fetch((res.evals, res.X)))
         stages["OUT"] = out_ok
         res.info["health"] = verdict_from_stages(stages).as_json_dict()
         res.info["recovery"] = recovery

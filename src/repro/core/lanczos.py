@@ -42,7 +42,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .instrument import DispatchCounter
+from .instrument import DispatchCounter, fetch, span
 from .looped import eigh_small, orthonormalize, qr_posdiag
 from .operators import ExplicitC, ImplicitC, Operator, apply_op, op_dim
 
@@ -287,7 +287,10 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
 
     Per restart the host issues O(1) device dispatches: one jitted
     whole-segment program, one ``_restart_math``, and a single-scalar
-    ``jax.device_get`` for the convergence verdict.
+    ``jax.device_get`` for the convergence verdict. Each is a span of
+    ``core.instrument``: ``repro.ke.segment``, ``repro.ke.restart`` and
+    ``repro.ke.sync`` (``restart=k``); the start block is
+    ``repro.ke.prep``, the Ritz vectors after the loop ``repro.ke.ritz``.
     """
     if isinstance(op, (ExplicitC, ImplicitC)):
         n = op_dim(op)
@@ -331,59 +334,61 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
 
     if key is None:
         key = jax.random.PRNGKey(272727)
-    X0 = _seed_block(v0, n, p, key, dtype)
     n_matvec = 0
-    if filter_degree > 0:
-        from .filtering import (chebyshev_filter_jit, estimate_bounds_jit,
-                                filter_interval, probe_steps)
-        kb = probe_steps(s, n)
-        theta_p, beta_k = _dispatch(estimate_bounds_jit, matvec,
-                                    jax.random.normal(
-                                        jax.random.fold_in(key, 2), (n,),
-                                        dtype), kb)
-        a, b, a0 = filter_interval(theta_p, beta_k, s, which)
-        X0 = _dispatch(chebyshev_filter_jit, matvec, X0, filter_degree,
-                       a, b, a0)
-        n_matvec += kb + filter_degree * p
-    V = jnp.zeros((n, m + p), dtype)
-    T = jnp.zeros((m + p, m + p), dtype)
-    Q0, _ = qr_posdiag(X0)
-    V = V.at[:, :p].set(Q0)
+    with span("ke.prep"):
+        X0 = _seed_block(v0, n, p, key, dtype)
+        if filter_degree > 0:
+            from .filtering import (chebyshev_filter_jit,
+                                    estimate_bounds_jit, filter_interval,
+                                    probe_steps)
+            kb = probe_steps(s, n)
+            theta_p, beta_k = _dispatch(estimate_bounds_jit, matvec,
+                                        jax.random.normal(
+                                            jax.random.fold_in(key, 2),
+                                            (n,), dtype), kb)
+            a, b, a0 = filter_interval(theta_p, beta_k, s, which)
+            X0 = _dispatch(chebyshev_filter_jit, matvec, X0, filter_degree,
+                           a, b, a0)
+            n_matvec += kb + filter_degree * p
+        V = jnp.zeros((n, m + p), dtype)
+        T = jnp.zeros((m + p, m + p), dtype)
+        Q0, _ = qr_posdiag(X0)
+        V = V.at[:, :p].set(Q0)
 
     j0 = 0
     theta = S = resid = None
+    n_restart, converged, health_ok = max_restarts, False, True
     for k_restart in range(max_restarts):
-        V, T, B_q = _dispatch(segment, V, T, jnp.asarray(j0))
+        with span("ke.segment"):
+            V, T, B_q = _dispatch(segment, V, T, jnp.asarray(j0))
         n_matvec += m - j0 * p
-        theta, S, resid, V_restart, T_new, all_conv, healthy = _dispatch(
-            _restart_math, V, T, B_q, jnp.asarray(tol_eff, dtype),
-            s=s, keep=keep, m=m, p=p, which=which,
-            resid_floor_rel=resid_floor_rel)
+        with span("ke.restart"):
+            theta, S, resid, V_restart, T_new, all_conv, healthy = _dispatch(
+                _restart_math, V, T, B_q, jnp.asarray(tol_eff, dtype),
+                s=s, keep=keep, m=m, p=p, which=which,
+                resid_floor_rel=resid_floor_rel)
         if callback is not None:
-            callback(k_restart, V, T, m)
+            with span("ke.checkpoint"):
+                callback(k_restart, V, T, m)
         # one fetch for both fused verdicts (same dispatch budget as the
         # single-scalar convergence test this replaces)
-        conv_ok, health_ok = (bool(x) for x in
-                              jax.device_get((all_conv, healthy)))
-        if not health_ok:
-            # the restart state is poisoned: stop burning restarts on
-            # NaNs (a NaN residual never compares <= thresh) and report
-            evecs = V[:, :m] @ S[:, :s]
-            return LanczosResult(theta[:s], evecs, n_matvec, k_restart + 1,
-                                 False, resid[:s], healthy=False)
-        if conv_ok:
-            evecs = V[:, :m] @ S[:, :s]
-            evecs = orthonormalize(evecs)
-            return LanczosResult(theta[:s], evecs, n_matvec, k_restart + 1,
-                                 True, resid[:s])
+        with span("ke.sync", restart=k_restart):
+            conv_ok, health_ok = map(bool, fetch((all_conv, healthy)))
+        # a poisoned restart state stops the loop too: no burning restarts
+        # on NaNs (a NaN residual never compares <= thresh)
+        if conv_ok or not health_ok:
+            n_restart, converged = k_restart + 1, conv_ok and health_ok
+            break
         # thick restart
         V, T = V_restart, T_new
         j0 = keep // p
 
-    evecs = V[:, :m] @ S[:, :s]
-    evecs = orthonormalize(evecs)
-    return LanczosResult(theta[:s], evecs, n_matvec, max_restarts, False,
-                         resid[:s])
+    with span("ke.ritz"):
+        evecs = V[:, :m] @ S[:, :s]
+        if health_ok:
+            evecs = orthonormalize(evecs)
+    return LanczosResult(theta[:s], evecs, n_matvec, n_restart, converged,
+                         resid[:s], healthy=health_ok)
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,8 @@ from jax.scipy.linalg import solve_triangular
 
 from repro.kernels import dispatch
 
+from .instrument import span
+
 #: tile edge of the looped factorizations and solves
 LOOP_BLOCK = 256
 #: output tile edge of ``matmul_tiled``
@@ -104,14 +106,16 @@ def eigh_small(T: jax.Array):
     (3e-11 relative eigenvalue error on a well-conditioned 256x256 matrix,
     where f64 LAPACK gives ~1e-15), which misses the Table-3 bars; on
     emulated f64 the host's LAPACK solves it through a callback (one
-    (m, m) transfer per restart)."""
+    (m, m) transfer per restart), in the span ``repro.ke.host_eigh`` on
+    whichever host thread runs the callback."""
     if emulated_f64(T.dtype):
         # one (m+1, m) result — w stacked on V: a callback with two results
         # does not lower inside a multi-device TPU program
         def host_eigh(t):
-            w, v = np.linalg.eigh(t)
-            wv = np.concatenate([w[..., None, :], v], axis=-2)
-            return wv.astype(t.dtype)
+            with span("ke.host_eigh"):
+                w, v = np.linalg.eigh(t)
+                wv = np.concatenate([w[..., None, :], v], axis=-2)
+                return wv.astype(t.dtype)
 
         shape = T.shape[:-2] + (T.shape[-2] + 1, T.shape[-1])
         wv = jax.pure_callback(host_eigh, jax.ShapeDtypeStruct(shape, T.dtype),

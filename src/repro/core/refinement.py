@@ -46,6 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.scipy.linalg import lu_factor, lu_solve, solve_triangular
 
+from .instrument import fetch
+
 # the shared Table-3 tolerance (tests/test_accuracy_harness.py asserts
 # both metrics against this same value)
 REFINE_TOL = 1e-12
@@ -174,12 +176,13 @@ def refine_eigenpairs(
     if key is None:
         key = jax.random.PRNGKey(1203)
 
-    sigma = float(_sigma(lam, which))
+    sigma = float(fetch(_sigma(lam, which)))
     lu, piv = _factor_f32(A, B, sigma)
 
-    resid, orth = _metrics(A, B, lam, X, s=s, which="smallest")
-    resid_traj = [float(resid)]
-    orth_traj = [float(orth)]
+    resid, orth = map(float, fetch(_metrics(A, B, lam, X, s=s,
+                                            which="smallest")))
+    resid_traj = [resid]
+    orth_traj = [orth]
     lam_q, X_q = _with_guards(lam, X, guard, which, key)
     steps = 0
     stalled = 0
@@ -188,8 +191,8 @@ def refine_eigenpairs(
     finite = True
     while (resid_traj[-1] > tol or orth_traj[-1] > tol) and steps < max_steps:
         lam_new, X_new = _jit_refine_step(lu, piv, A, B, lam_q, X_q)
-        resid, orth = _metrics(A, B, lam_new, X_new, s=s, which=which)
-        r, o = float(resid), float(orth)
+        r, o = map(float, fetch(_metrics(A, B, lam_new, X_new, s=s,
+                                         which=which)))
         if not (np.isfinite(r) and np.isfinite(o)):
             finite = False
             break                      # degenerate input; keep the last good
@@ -200,8 +203,9 @@ def refine_eigenpairs(
         if r <= tol and o <= tol:
             break
         lam_s, _ = _select(lam_q, X_q, s, which)
-        end = float(lam_s[0] if which == "smallest" else lam_s[-1])
-        sig2 = float(_sigma(lam_s, which))
+        end, sig2 = map(float, fetch((
+            lam_s[0] if which == "smallest" else lam_s[-1],
+            _sigma(lam_s, which))))
         if (refactors < 3
                 and abs(sig2 - sigma) > 0.25 * abs(end - sigma)):
             # the Ritz values moved enough that a fresh shift contracts

@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import functools
 import math
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -52,7 +51,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.filtering import (chebyshev_filter, estimate_bounds,
                                   filter_interval, probe_steps)
-from repro.core.instrument import DispatchCounter
+from repro.core.instrument import (DispatchCounter, fetch, span, stage,
+                                   timed, wait)
 from repro.core.lanczos import (_restart_math, _segment_impl,
                                 default_subspace, restart_schedule)
 from repro.core.linalg_utils import symmetrize
@@ -74,23 +74,12 @@ from .sharded_la import (_n_row_shards, _row_axes, _row_spec, _row_sharded,
                          dist_trsm_left_t)
 
 
-def _make_timer(times: dict):
-    """Per-stage wall-clock accumulator shared by both pipelines."""
-    def timed(name, fn, *args):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        times[name] = times.get(name, 0.0) + (time.perf_counter() - t0)
-        return out
-    return timed
-
-
-def _standard_form(mesh, A, B, timed):
+def _standard_form(mesh, A, B, times):
     """GS1 + GS2 (shared by KE and TT): B = U^T U, C = U^{-T} A U^{-1}
     via two transposed panel solves, resymmetrized."""
-    U = timed("GS1", lambda b: dist_cholesky(mesh, b), B)
-    T1 = timed("GS2", lambda a: dist_trsm_left_t(mesh, U, a), A)
-    C = timed("GS2", lambda t: dist_trsm_left_t(mesh, U, t.T).T, T1)
+    U = timed(times, "GS1", dist_cholesky, mesh, B)
+    T1 = timed(times, "GS2", dist_trsm_left_t, mesh, U, A)
+    C = timed(times, "GS2", lambda t: dist_trsm_left_t(mesh, U, t.T).T, T1)
     return U, 0.5 * (C + C.T)
 
 
@@ -152,7 +141,7 @@ def ke_restart_program(mesh, n: int, p: int, m: int, s: int, keep: int,
     assert ok, (n, R, cm)
     ncm = n // cm
 
-    def local(c_blk, V, T, j0, tol_eff):
+    def ke_restart(c_blk, V, T, j0, tol_eff):
         matvec = _fused_block_matvec(c_blk, ncm, ax)
         V, T, B_q = _segment_impl(matvec, V, T, j0, p)
         # the restart math carries the fused health sentinel — the
@@ -163,7 +152,7 @@ def ke_restart_program(mesh, n: int, p: int, m: int, s: int, keep: int,
         evecs = orthonormalize(V[:, :m] @ S[:, :s])
         return theta[:s], resid[:s], V_r, T_new, conv, healthy, evecs
 
-    prog = shard_map(local, mesh=mesh,
+    prog = shard_map(ke_restart, mesh=mesh,
                      in_specs=(P(rs, "model"), P(None, None), P(None, None),
                                P(), P()),
                      out_specs=(P(None), P(None), P(None, None),
@@ -184,7 +173,7 @@ def ke_prep_program(mesh, n: int, p: int, kb: int, degree: int, s: int,
     assert ok, (n, R, cm)
     ncm = n // cm
 
-    def local(c_blk, X0):
+    def ke_prep(c_blk, X0):
         matvec = _fused_block_matvec(c_blk, ncm, ax)
         theta, beta_k = estimate_bounds(matvec, X0[:, 0], kb)
         a, b, a0 = filter_interval(theta, beta_k, s, which)
@@ -192,7 +181,7 @@ def ke_prep_program(mesh, n: int, p: int, kb: int, degree: int, s: int,
         Q0, _ = qr_posdiag(Xf)
         return Q0
 
-    prog = shard_map(local, mesh=mesh,
+    prog = shard_map(ke_prep, mesh=mesh,
                      in_specs=(P(rs, "model"), P(None, None)),
                      out_specs=P(None, None),
                      check_vma=False)
@@ -271,7 +260,6 @@ def solve_ke_distributed(
     if key is None:
         key = jax.random.PRNGKey(20120520)
     times = {}
-    timed = _make_timer(times)
 
     # an n that does not tile the mesh is padded to one that does: A with
     # zeros, B with an identity block. Then U and C are block-diagonal, C
@@ -284,96 +272,107 @@ def solve_ke_distributed(
     if n_k != n:
         A = jnp.pad(A, ((0, n_k - n), (0, n_k - n)))
         B = pad_identity(B, n_k)
-    U, C = _standard_form(mesh, A, B, timed)
+    U, C = _standard_form(mesh, A, B, times)
     arp_which = "SA" if which == "smallest" else "LA"
     # work dtype of the basis/restart math; the operand may sit lower
     wdtype = jnp.float32 if demoted else C.dtype
     keep, _ = restart_schedule(s, m, p)
 
-    t0 = time.perf_counter()
     healthy = True
     resumed_from = None
-    # the Krylov operand lives 2-D-sharded: rows over data axes, cols over
-    # 'model' — the layout the fused block matvec consumes
-    if demoted:
-        C = C.astype(cdtype)
-    dtype = C.dtype
-    C = jax.device_put(C, NamedSharding(mesh, P(rs, "model")))
     rep = NamedSharding(mesh, P(None, None))
-    dname = jnp.dtype(dtype).name
 
     def padded(M):                  # (n, k) -> (n_k, k), replicated
         return jax.device_put(jnp.pad(M, ((0, n_k - n), (0, 0))), rep)
 
-    X0 = padded(jax.random.normal(key, (n, p), wdtype))
-    n_matvec = 0
-    if filter_degree > 0:
-        kb = probe_steps(s, n)
-        prep = ke_prep_program(mesh, n_k, p, kb, filter_degree, s,
-                               arp_which, dname)
-        Q0 = _dispatch(prep, C, X0)
-        n_matvec += kb + filter_degree * p
-    else:
-        Q0, _ = qr_posdiag(X0)
-    V = jax.device_put(
-        jnp.zeros((n_k, m + p), wdtype).at[:, :p].set(Q0), rep)
-    T = jax.device_put(jnp.zeros((m + p, m + p), wdtype), rep)
-    # the demoted operand floors the attainable residual at
-    # ~eps(cdtype) * ||C||; ask for no more (core.lanczos uses the same 8x
-    # floor on its local demoted path)
-    eps = float(jnp.finfo(dtype).eps)
-    eps_eff = 8.0 * eps if demoted else eps
-    tol_eff = jnp.asarray(tol if tol > 0.0 else eps_eff, wdtype)
-    prog = ke_restart_program(mesh, n_k, p, m, s, keep, arp_which, dname)
-    j0 = 0
-    k0 = 0
-    converged = False
-    if checkpoint_dir is not None and resume:
-        from . import checkpoint as _ckpt
-        # dict keys flatten sorted, so the template's {T, V} order matches
-        # what save() wrote; V is stored unpadded, so a resume may land on
-        # a mesh with another padding
-        got = _ckpt.load_latest(
-            checkpoint_dir, {"T": jnp.zeros((m + p, m + p), wdtype),
-                             "V": jnp.zeros((n, m + p), wdtype)})
-        if got is not None:
-            step, tree, extra = got
-            V = padded(tree["V"])
-            T = jax.device_put(tree["T"], rep)
-            j0 = int(extra.get("j", keep // p))
-            k0 = int(step) + 1
-            n_matvec = int(extra.get("n_matvec", n_matvec))
-            resumed_from = int(step)
-    n_restart = max_restarts
-    for k_restart in range(k0, max_restarts):
-        lam, resid, V, T, conv, healthy_dev, Y = _dispatch(
-            prog, C, V, T, jnp.asarray(j0), tol_eff)
-        n_matvec += m - j0 * p
-        j0 = keep // p
-        # one fetch for both fused verdicts
-        conv_ok, health_ok = (bool(x) for x in
-                              jax.device_get((conv, healthy_dev)))
-        if checkpoint_dir is not None and k_restart % checkpoint_every == 0:
-            # the POST-restart (V, T) — the state the next segment consumes
-            # — so a resumed solve replays the identical restart arithmetic
+    with stage(times, "KE_iter"):
+        # the Krylov operand lives 2-D-sharded: rows over data axes, cols
+        # over 'model' — the layout the fused block matvec consumes
+        with span("ke.place"):
+            if demoted:
+                C = C.astype(cdtype)
+            dtype = C.dtype
+            C = jax.device_put(C, NamedSharding(mesh, P(rs, "model")))
+        dname = jnp.dtype(dtype).name
+        n_matvec = 0
+        with span("ke.prep"):
+            X0 = padded(jax.random.normal(key, (n, p), wdtype))
+            if filter_degree > 0:
+                kb = probe_steps(s, n)
+                prep = ke_prep_program(mesh, n_k, p, kb, filter_degree, s,
+                                       arp_which, dname)
+                Q0 = _dispatch(prep, C, X0)
+                n_matvec += kb + filter_degree * p
+            else:
+                Q0, _ = qr_posdiag(X0)
+        with span("ke.place"):
+            V = jax.device_put(
+                jnp.zeros((n_k, m + p), wdtype).at[:, :p].set(Q0), rep)
+            T = jax.device_put(jnp.zeros((m + p, m + p), wdtype), rep)
+        # the demoted operand floors the attainable residual at
+        # ~eps(cdtype) * ||C||; ask for no more (core.lanczos uses the
+        # same 8x floor on its local demoted path)
+        eps = float(jnp.finfo(dtype).eps)
+        eps_eff = 8.0 * eps if demoted else eps
+        tol_eff = jnp.asarray(tol if tol > 0.0 else eps_eff, wdtype)
+        prog = ke_restart_program(mesh, n_k, p, m, s, keep, arp_which, dname)
+        j0 = 0
+        k0 = 0
+        converged = False
+        if checkpoint_dir is not None and resume:
             from . import checkpoint as _ckpt
-            _ckpt.save(checkpoint_dir, k_restart, {"V": V[:n], "T": T},
-                       extra={"kind": "ke_dist", "j": int(j0),
-                              "n_matvec": int(n_matvec)},
-                       keep=checkpoint_keep)
-        if preempt_after is not None and k_restart - k0 + 1 >= preempt_after:
-            from repro.resilience.faults import SimulatedPreemption
-            raise SimulatedPreemption(k_restart)
-        if not health_ok:
-            healthy = False
-            n_restart = k_restart + 1
-            break
-        if conv_ok:
-            converged = True
-            n_restart = k_restart + 1
-            break
-    jax.block_until_ready(Y)
-    times["KE_iter"] = time.perf_counter() - t0
+            # dict keys flatten sorted, so the template's {T, V} order
+            # matches what save() wrote; V is stored unpadded, so a resume
+            # may land on a mesh with another padding
+            with span("ke.checkpoint"):
+                got = _ckpt.load_latest(
+                    checkpoint_dir,
+                    {"T": jnp.zeros((m + p, m + p), wdtype),
+                     "V": jnp.zeros((n, m + p), wdtype)})
+            if got is not None:
+                step, tree, extra = got
+                with span("ke.place"):
+                    V = padded(tree["V"])
+                    T = jax.device_put(tree["T"], rep)
+                j0 = int(extra.get("j", keep // p))
+                k0 = int(step) + 1
+                n_matvec = int(extra.get("n_matvec", n_matvec))
+                resumed_from = int(step)
+        n_restart = max_restarts
+        for k_restart in range(k0, max_restarts):
+            with span("ke.restart"):
+                lam, resid, V, T, conv, healthy_dev, Y = _dispatch(
+                    prog, C, V, T, jnp.asarray(j0), tol_eff)
+            n_matvec += m - j0 * p
+            j0 = keep // p
+            # one fetch for both fused verdicts
+            with span("ke.sync", restart=k_restart):
+                conv_ok, health_ok = map(bool, fetch((conv, healthy_dev)))
+            if (checkpoint_dir is not None
+                    and k_restart % checkpoint_every == 0):
+                # the POST-restart (V, T) — the state the next segment
+                # consumes — so a resumed solve replays the identical
+                # restart arithmetic
+                from . import checkpoint as _ckpt
+                with span("ke.checkpoint"):
+                    _ckpt.save(checkpoint_dir, k_restart,
+                               fetch({"V": V[:n], "T": T}),
+                               extra={"kind": "ke_dist", "j": int(j0),
+                                      "n_matvec": int(n_matvec)},
+                               keep=checkpoint_keep)
+            if (preempt_after is not None
+                    and k_restart - k0 + 1 >= preempt_after):
+                from repro.resilience.faults import SimulatedPreemption
+                raise SimulatedPreemption(k_restart)
+            if not health_ok:
+                healthy = False
+                n_restart = k_restart + 1
+                break
+            if conv_ok:
+                converged = True
+                n_restart = k_restart + 1
+                break
+        wait(Y)
 
     if demoted:
         lam, Y = lam.astype(A.dtype), Y.astype(A.dtype)
@@ -381,15 +380,16 @@ def solve_ke_distributed(
     lam, Y = lam[order], Y[:, order]
 
     # BT1: X = U^{-1} Y
-    X = timed("BT1", lambda y: dist_trsm_left(mesh, U, y), Y)[:n]
+    X = timed(times, "BT1", dist_trsm_left, mesh, U, Y)[:n]
 
     if invert:
-        lam = 1.0 / lam
-        order = jnp.argsort(lam)
-        lam, X = lam[order], X[:, order]
-        from repro.core.residuals import b_normalize
-        X = b_normalize(X, jax.device_put(
-            B_orig, NamedSharding(mesh, P(None, None))))
+        with span("finalize"):
+            lam = 1.0 / lam
+            order = jnp.argsort(lam)
+            lam, X = lam[order], X[:, order]
+            from repro.core.residuals import b_normalize
+            X = b_normalize(X, jax.device_put(
+                B_orig, NamedSharding(mesh, P(None, None))))
 
     if return_info:
         info = {"stage_times": times, "n_matvec": int(n_matvec),
@@ -541,7 +541,7 @@ def tt3_program(mesh, n: int, s_pad: int, max_iters: int, iters: int,
     assert s_pad % n_dev == 0, (s_pad, n_dev)
     s_loc = s_pad // n_dev
 
-    def local(d, e, ks_loc, X0):
+    def tt3_eig(d, e, ks_loc, X0):
         lam_loc = bisect_eigenvalues(d, e, ks_loc, max_iters=max_iters,
                                      unroll=unroll)
         lam = jax.lax.all_gather(lam_loc, part, axis=0, tiled=True)
@@ -568,7 +568,7 @@ def tt3_program(mesh, n: int, s_pad: int, max_iters: int, iters: int,
         Z = jax.lax.fori_loop(0, iters, one_round, X0)
         return lam, Z
 
-    prog = shard_map(local, mesh=mesh,
+    prog = shard_map(tt3_eig, mesh=mesh,
                      in_specs=(P(None), P(None), P(part), P(None, None)),
                      out_specs=(P(None), P(None, None)),
                      check_vma=False)
@@ -648,15 +648,13 @@ def solve_tt_distributed(
     if key is None:
         key = jax.random.PRNGKey(20120520)
     times = {}
-    timed = _make_timer(times)
 
-    U, C = _standard_form(mesh, A, B, timed)
+    U, C = _standard_form(mesh, A, B, times)
     if demoted:
         C = C.astype(cdtype)
 
     # TT1: dense -> band, Q1 stays mesh-resident
-    W, Q1 = timed("TT1", lambda c: dist_reduce_to_band(mesh, c, band_width),
-                  C)
+    W, Q1 = timed(times, "TT1", dist_reduce_to_band, mesh, C, band_width)
 
     # TT2: band -> tridiagonal, replicated (O(n^2 w) wavefront Givens work
     # over packed (w+1, n) band storage). No Q2 is materialized — the
@@ -664,7 +662,7 @@ def solve_tt_distributed(
     # TT4, so Q1 — the O(n^2) object — never gathers and Q2 never exists.
     rep = NamedSharding(mesh, P(None, None))
     W_rep = jax.device_put(W, rep)
-    chase = timed("TT2", lambda wr: band_chase(
+    chase = timed(times, "TT2", lambda wr: band_chase(
         _jit_pack(wr, band_width), band_width), W_rep)
 
     # TT3: selected eigenpairs of the tridiagonal — each device bisects +
@@ -674,23 +672,23 @@ def solve_tt_distributed(
     d64 = chase.d.astype(A.dtype)
     e64 = chase.e.astype(A.dtype)
     if shard_tt3:
-        lam, Z = timed("TT3", lambda d, e: dist_tridiag_eig(
-            mesh, d, e, ks, key), d64, e64)
+        lam, Z = timed(times, "TT3", dist_tridiag_eig, mesh, d64, e64, ks,
+                       key)
     else:
-        lam, Z = timed("TT3", lambda d, e: eigh_tridiag_selected(
-            d, e, ks, key), d64, e64)
+        lam, Z = timed(times, "TT3", eigh_tridiag_selected, d64, e64, ks,
+                       key)
 
     # TT4: Y = Q1 (Q2 Z) — Q2 Z replays the recorded rotations over the
     # replicated (n, s) slab; the product against the row-sharded Q1 is a
     # collective-free panel matmul
     Zc = Z.astype(cdtype) if demoted else Z
-    Y = timed("TT4", lambda z: dist_panel_matmul(
+    Y = timed(times, "TT4", lambda z: dist_panel_matmul(
         mesh, Q1, apply_q2(chase, z, band_width)), Zc)
     if demoted:
         Y = Y.astype(A.dtype)
 
     # BT1: X = U^{-1} Y
-    X = timed("BT1", lambda y: dist_trsm_left(mesh, U, y), Y)
+    X = timed(times, "BT1", dist_trsm_left, mesh, U, Y)
 
     if return_info:
         info = {"stage_times": times, "band_width": int(band_width),

@@ -222,7 +222,7 @@ def band_sweep_program(mesh, n: int, w: int, dtype_name: str):
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     dtype = jnp.dtype(dtype_name)
 
-    def local(m_blk, q_blk):
+    def tt1_sweep(m_blk, q_blk):
         # global row offset of this shard (row axes merge in mesh order)
         shard = jnp.zeros((), jnp.int32)
         for a in row_axes:
@@ -255,7 +255,7 @@ def band_sweep_program(mesh, n: int, w: int, dtype_name: str):
         m_blk = jnp.where(dist_band <= w, m_blk, jnp.zeros((), dtype))
         return m_blk, q_blk
 
-    sweep = shard_map(local, mesh=mesh,
+    sweep = shard_map(tt1_sweep, mesh=mesh,
                       in_specs=(P(rs, None), P(rs, None)),
                       out_specs=(P(rs, None), P(rs, None)),
                       check_vma=False)
@@ -301,8 +301,12 @@ def _row_sharded(mesh, M):
 @functools.lru_cache(maxsize=None)
 def _jit_blocked(fn, block: int, out_sharding):
     """One jitted executable per (kernel, panel size, output layout):
-    a fresh jax.jit per call would retrace/recompile every invocation."""
-    return jax.jit(partial(fn, block=block), out_shardings=out_sharding)
+    a fresh jax.jit per call would retrace/recompile every invocation.
+    The program takes the kernel's name (a bare partial traces as
+    ``jit(<unknown>)``)."""
+    blocked = partial(fn, block=block)
+    blocked.__name__ = blocked.__qualname__ = fn.__name__
+    return jax.jit(blocked, out_shardings=out_sharding)
 
 
 def dist_cholesky(mesh, B, block=None):

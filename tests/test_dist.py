@@ -624,3 +624,68 @@ def test_distributed_ke_pipeline_end_to_end():
                          text=True, env=env, cwd=os.path.dirname(
                              os.path.dirname(os.path.abspath(__file__))))
     assert "DIST_KE_OK" in out.stdout, out.stdout + out.stderr[-3000:]
+
+
+def test_distributed_ke_spans_counters_and_program_names_four_device():
+    """The mesh path of ``core.solve`` on a 2x2 host mesh: its stage and
+    Krylov spans nest inside ``repro.solve`` (one ``repro.ke.sync`` a
+    restart), ``info["counters"]`` counts ``n_restart + 6`` host syncs
+    (GS1 wait, two GS2 waits, a verdict a restart, the KE_iter and BT1
+    waits and the output sentinel's fetch) and one dispatch a restart,
+    and no program it lowers is named ``<unknown>`` or ``local``."""
+    code = textwrap.dedent("""
+        import glob, os, tempfile
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax
+        jax.config.update("jax_enable_x64", True)
+        from jax.profiler import ProfileData
+        from repro.core import solve
+        from repro.data.problems import md_like
+        from repro.dist.partitioning import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
+        prob = md_like(64)
+        kw = dict(variant="KE", invert=True, tol=1e-9, krylov_block=4,
+                  mesh=mesh)
+        lowered = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda ev, d, **a: lowered.append(a.get("fun_name", "")))
+        first = solve(prob.A, prob.B, 4, **kw)
+        assert first.info["counters"]["compiles"] >= 5, first.info
+        for name in ("jit(cholesky_blocked)", "jit(_trsm_lt_blocked)",
+                     "jit(_trsm_l_blocked)", "jit(ke_restart)"):
+            assert name in lowered, (name, sorted(set(lowered)))
+        bad = [f for f in lowered if "<unknown>" in f or "(local)" in f]
+        assert not bad, bad
+        log_dir = tempfile.mkdtemp()
+        with jax.profiler.trace(log_dir):
+            res = solve(prob.A, prob.B, 4, **kw)
+        c, n_restart = res.info["counters"], res.info["n_restart"]
+        assert res.info["converged"] and n_restart >= 2, res.info
+        assert c == {"host_syncs": n_restart + 6, "dispatches": n_restart,
+                     "compiles": 0}, (c, n_restart)
+        path = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")[0]
+        spans = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+            for p in ProfileData.from_file(path).planes for ln in p.lines
+            for e in ln.events if e.name.startswith("repro."))
+        names = [sp[2] for sp in spans]
+        top = spans[names.index("repro.solve")]
+        ke = spans[names.index("repro.KE_iter")]
+        assert all(top[0] <= a and b <= top[1] for a, b, _, _ in spans)
+        for stage in ("GS1", "GS2", "KE_iter", "BT1", "finalize"):
+            assert "repro." + stage in names, names
+        assert names.count("repro.GS2") == 2
+        inner = [sp for sp in spans if sp[2].startswith("repro.ke.")]
+        assert {sp[2] for sp in inner} == {"repro.ke.place", "repro.ke.prep",
+                                          "repro.ke.restart",
+                                          "repro.ke.sync"}, names
+        assert all(ke[0] <= a and b <= ke[1] for a, b, _, _ in inner)
+        assert [sp[3]["restart"] for sp in inner
+                if sp[2] == "repro.ke.sync"] == list(range(n_restart))
+        print("DIST_SPANS_OK")
+    """)
+    env = dict(os.environ, PYTHONPATH="src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, cwd=os.path.dirname(
+                             os.path.dirname(os.path.abspath(__file__))))
+    assert "DIST_SPANS_OK" in out.stdout, out.stdout + out.stderr[-3000:]
