@@ -31,9 +31,10 @@ from repro.resilience.recovery import (SolverError, cholesky_shift_taus,
 
 from .back_transform import back_transform_generalized
 from .cholesky import cholesky_blocked, cholesky_upper, diag_shifted
-from .instrument import counting, fetch, span, timed
+from . import looped
+from .instrument import count, counting, fetch, span, timed
 from .lanczos import default_subspace, lanczos_solve
-from .operators import ExplicitC, ImplicitC
+from .operators import ExplicitC, ImplicitC, SplitC, split_c
 from .precision import (compute_dtype, ensure_strong, exact_matmuls,
                         validate_precision)
 from .refinement import REFINE_TOL, refine_eigenpairs
@@ -91,6 +92,14 @@ def _gs2_trsm_fused(A, U):
     return C, array_finite(C)
 
 
+def _gs2_trsm_split(A, U):
+    """GS2 for KE on emulated f64: C in its int8 split form, with the
+    verdict taken on C (an int8 slice holds no NaN). C is this program's
+    scratch only, so C and its split are never live together."""
+    C, ok = _gs2_trsm_fused(A, U)
+    return split_c(C), ok
+
+
 def _gs2_sygst_fused(A, U, block):
     C = to_standard_sygst(A, U, block=block)
     return C, array_finite(C)
@@ -100,6 +109,7 @@ _jit_chol = jax.jit(_chol_fused)
 _jit_chol_blocked = jax.jit(_chol_blocked_fused, static_argnames=("block",))
 _jit_chol_ladder = jax.jit(_chol_ladder_fused)
 _jit_gs2_trsm = jax.jit(_gs2_trsm_fused)
+_jit_gs2_split = jax.jit(_gs2_trsm_split)
 _jit_gs2_sygst = jax.jit(_gs2_sygst_fused, static_argnames=("block",))
 _jit_td1 = jax.jit(tridiagonalize)
 _jit_td1_blocked = jax.jit(tridiagonalize_blocked, static_argnames=("panel",))
@@ -328,10 +338,16 @@ def _solve_once(
     stage_health["GS1"] = gs1_ok
 
     # ---- GS2: C = U^{-T} A U^{-1} (not for KI) ---------------------------
+    # KE on emulated f64 (a TPU) multiplies C in int8 slices: GS2 returns
+    # them instead of C, and each block step's product reads only them
+    split = (variant == "KE" and gs2 == "trsm" and not demoted
+             and looped.emulated_f64(A.dtype) and n > looped.MM_TILE)
     C = None
     if variant in ("TD", "TT", "KE"):
         Ag = faults.poison_stage("GS2", A)
-        if gs2 == "sygst":
+        if split:
+            C, gs2_ok = timed(times, "GS2", _jit_gs2_split, Ag, U)
+        elif gs2 == "sygst":
             C, gs2_ok = timed(times, "GS2", _jit_gs2_sygst, Ag, U,
                               block=block)
         else:
@@ -413,12 +429,15 @@ def _solve_once(
         Y = Y.astype(jnp.float64)
     else:
         arp_which = "SA" if want_small else "LA"
-        if variant == "KE":
+        if split:
+            # the poisoned scales reach every row the operator reads
+            op = SplitC(C.Q, faults.poison_stage("KE_iter", C.scale))
+            count("split_operator")
+        elif variant == "KE":
             op = ExplicitC(faults.poison_stage("KE_iter", C))
-            prefix = "KE"
         else:
             op = ImplicitC(faults.poison_stage("KI_iter", A), U)
-            prefix = "KI"
+        prefix = variant
         if m is None:
             m = default_subspace(s, n, p)
         elif p > 1 and m % p:
@@ -542,9 +561,10 @@ def solve(
 
     Every solve carries ``info['health']`` (per-stage verdicts, JSON-
     clean), ``info['recovery']`` (the rungs taken, possibly empty) and
-    ``info['counters']`` (``core.instrument``'s host syncs, dispatches
-    and lowered programs of the whole call, ints). The call is the
-    profiler span ``repro.solve``, parent of every stage's span.
+    ``info['counters']`` (``core.instrument``'s host syncs, dispatches,
+    lowered programs and KE runs on the split operator of the whole
+    call, ints). The call is the profiler span ``repro.solve``, parent of
+    every stage's span.
     """
     validate_on_failure(on_failure)
     kw: Dict[str, Any] = dict(

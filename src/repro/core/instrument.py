@@ -10,8 +10,9 @@ to ``stage_times[name]``; ``timed`` runs a stage in it and waits for the
 result.
 
 Counters. ``counting()`` opens a fresh ``{"host_syncs", "dispatches",
-"compiles"}`` dict of ints for the calling thread (``gsyeig.solve`` opens
-one per solve and returns it as ``info["counters"]``); while it is open:
+"compiles", "split_operator"}`` dict of ints for the calling thread
+(``gsyeig.solve`` opens one per solve and returns it as
+``info["counters"]``); while it is open:
 
 * ``host_syncs`` counts every wait of the calling thread on the device,
   which the solver routes through ``wait`` (``block_until_ready``) and
@@ -22,7 +23,9 @@ one per solve and returns it as ``info["counters"]``); while it is open:
   tests pin);
 * ``compiles`` counts programs lowered on the thread, by a
   ``jax.monitoring`` listener on the jaxpr-to-MLIR lowering, which
-  precedes both a backend compile and a persistent-cache load.
+  precedes both a backend compile and a persistent-cache load;
+* ``split_operator`` counts the KE iterations that ran on the int8
+  split form of C (``core.operators.SplitC``): 1 a solve there, else 0.
 
 Counters nest: an inner ``counting()`` counts into the outer ones too.
 """
@@ -38,7 +41,7 @@ import jax
 PREFIX = "repro."
 #: the monitoring event of one jaxpr lowered to an MLIR module
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-COUNTERS = ("host_syncs", "dispatches", "compiles")
+COUNTERS = ("host_syncs", "dispatches", "compiles", "split_operator")
 
 _local = threading.local()
 
@@ -55,7 +58,7 @@ def _open() -> list:
     return stack
 
 
-def _count(key: str) -> None:
+def count(key: str) -> None:
     """Count one ``key`` in every counter open on this thread."""
     for c in _open():
         c[key] += 1
@@ -75,13 +78,13 @@ def counting():
 
 def wait(x):
     """``jax.block_until_ready(x)``, counted as one host sync."""
-    _count("host_syncs")
+    count("host_syncs")
     return jax.block_until_ready(x)
 
 
 def fetch(x):
     """``jax.device_get(x)``, counted as one host sync."""
-    _count("host_syncs")
+    count("host_syncs")
     return jax.device_get(x)
 
 
@@ -104,7 +107,7 @@ def timed(times: Dict[str, float], name: str, fn, /, *args, **kwargs):
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
     if event == LOWERING_EVENT:
-        _count("compiles")
+        count("compiles")
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
@@ -127,5 +130,5 @@ class DispatchCounter:
 
     def __call__(self, fn, *args, **kwargs):
         self._count += 1
-        _count("dispatches")
+        count("dispatches")
         return fn(*args, **kwargs)

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 
 from .instrument import DispatchCounter, fetch, span
 from .looped import eigh_small, orthonormalize, qr_posdiag
-from .operators import ExplicitC, ImplicitC, Operator, apply_op, op_dim
+from .operators import (OPERATORS, ExplicitC, ImplicitC, Operator,
+                        apply_op, op_dim, op_dtype)
 
 
 class LanczosResult(NamedTuple):
@@ -142,7 +143,7 @@ def _make_segment(op, use_kernel: bool, p: int,
     static ``compute_dtype`` name; bare matvec callables — e.g. a
     distributed closure — get a per-solve jit (the closure is stable
     across the restart loop, so each solve compiles the segment once)."""
-    if isinstance(op, (ExplicitC, ImplicitC)):
+    if isinstance(op, OPERATORS):
         return lambda V, T, j0: _lanczos_segment(
             op, V, T, j0, use_kernel=use_kernel, p=p,
             compute_dtype=compute_dtype)
@@ -266,7 +267,7 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
                   compute_dtype=None) -> LanczosResult:
     """Host-driven thick-restart block Lanczos for s extremal eigenpairs.
 
-    `op` is an Operator pytree (ExplicitC/ImplicitC) or any traceable
+    `op` is an Operator pytree (``operators.OPERATORS``) or any traceable
     block-matvec callable X -> C X on (n, p) blocks (for ``p == 1`` a
     plain ``lambda v: C @ v`` works on the (n, 1) column). For callables,
     the problem dimension comes from `v0` (or the explicit `n`).
@@ -292,9 +293,9 @@ def lanczos_solve(op, s: int, which: str = "SA", m: int | None = None,
     ``repro.ke.sync`` (``restart=k``); the start block is
     ``repro.ke.prep``, the Ritz vectors after the loop ``repro.ke.ritz``.
     """
-    if isinstance(op, (ExplicitC, ImplicitC)):
+    if isinstance(op, OPERATORS):
         n = op_dim(op)
-        dtype = (op.C if isinstance(op, ExplicitC) else op.A).dtype
+        dtype = op_dtype(op)
         matvec = lambda X: apply_op(op, X, use_kernel=use_kernel)  # noqa: E731
     else:
         if n is None:

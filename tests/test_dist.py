@@ -662,7 +662,7 @@ def test_distributed_ke_spans_counters_and_program_names_four_device():
         c, n_restart = res.info["counters"], res.info["n_restart"]
         assert res.info["converged"] and n_restart >= 2, res.info
         assert c == {"host_syncs": n_restart + 6, "dispatches": n_restart,
-                     "compiles": 0}, (c, n_restart)
+                     "compiles": 0, "split_operator": 0}, (c, n_restart)
         path = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")[0]
         spans = sorted(
             (e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))

@@ -128,7 +128,8 @@ def test_counters_nest_and_stay_on_their_thread():
         t = threading.Thread(target=elsewhere)
         t.start()
         t.join()
-    assert inner == {"host_syncs": 1, "dispatches": 1, "compiles": 0}
+    assert inner == {"host_syncs": 1, "dispatches": 1, "compiles": 0,
+                     "split_operator": 0}
     assert outer["host_syncs"] == 2 and outer["dispatches"] == 1
     assert seen["host_syncs"] == 1
     assert counter.count() == 1

@@ -12,13 +12,17 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under pytest-xdist every worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.cholesky import cholesky_blocked
+from repro.core.lanczos import _lanczos_segment, default_subspace
 from repro.core.looped import solve_upper_looped
+from repro.core.operators import SplitC
 from repro.core.tridiag_eig import default_tridiag_method
 from repro.kernels import dispatch
 from repro.kernels.gemm.kernel import gemm_pallas
@@ -167,3 +171,70 @@ def test_looped_stage_programs_compile_at_md_width(one_chip):
                    _spec(one_chip, N_MD, 100, dtype=f64))
     for c in (gs1, bt1):
         assert c.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+          "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+          "f64": 8}
+_ARRAY = re.compile(r"\b(" + "|".join(_BYTES) + r")\[([\d,]*)\]")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$")
+
+
+def _array_bytes(shape: str) -> int:
+    """Bytes of the largest array in an HLO shape (a tuple's too)."""
+    out = 0
+    for dtype, dims in _ARRAY.findall(shape):
+        size = _BYTES[dtype]
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        out = max(out, size)
+    return out
+
+
+def _hlo(text: str):
+    """(instructions by computation, computations a fusion calls, the
+    shape of every instruction) of a compiled module's text."""
+    comps, fused, shapes, cur = {}, set(), {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and line.startswith(("%", "ENTRY")):
+            cur = line.split()[line.startswith("ENTRY")].lstrip("%")
+            comps[cur] = []
+        elif (m := _INSTR.match(line)) and cur is not None:
+            name, shape, opcode, rest = m.groups()
+            comps[cur].append((name, shape, opcode, rest))
+            shapes[name] = shape
+            if opcode == "fusion":
+                fused.update(re.findall(r"calls=%([\w.-]+)", rest))
+    return comps, fused, shapes
+
+
+def test_split_krylov_segment_reads_int8_slices_at_md_width(one_chip,
+                                                            monkeypatch):
+    """KE's segment on the split operator (md-ke: n=9,997, p=4): each block
+    step is int8 products into int32 on the slices as they are. No fusion
+    of the program writes an array of n^2 bytes, one int8 slice of C: the
+    f64 product re-splits each 1,024-row tile of C into f32[8,1024,n]
+    pieces, 3.3 n^2 bytes, at every step. The temporaries stay under 1 GB
+    (the f64 product's take 2.3 GB)."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    n, p = N_MD, 4
+    m = default_subspace(100, n, p)
+    op = SplitC(_spec(one_chip, n, 8, n, dtype=jnp.int8),
+                _spec(one_chip, n, dtype=jnp.float64))
+    compiled = _lanczos_segment.lower(
+        op, _spec(one_chip, n, m + p, dtype=jnp.float64),
+        _spec(one_chip, m + p, m + p, dtype=jnp.float64),
+        _spec(one_chip, dtype=jnp.int32), p=p).compile()
+    comps, fused, shapes = _hlo(compiled.as_text())
+    written = [(_array_bytes(shape), name)
+               for comp, instrs in comps.items() if comp not in fused
+               for name, shape, opcode, _ in instrs if opcode == "fusion"]
+    assert max(written) < (n * n, ""), max(written)
+    int8_products = [
+        name for instrs in comps.values()
+        for name, shape, opcode, rest in instrs
+        if opcode == "convolution" and shape.startswith("s32[")
+        and all(shapes[a].startswith("s8[")
+                for a in re.findall(r"%([\w.-]+)", rest.split(")")[0]))]
+    assert int8_products
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
